@@ -29,6 +29,7 @@ import time
 from repro.core import (DagArrive, DagDepart, FleetController, RateChange,
                         VmAdd, VmFail, diamond_dag, linear_dag,
                         paper_library, plan_fleet, star_dag, traffic_dag)
+from repro.core.online import Event
 from repro.core.scheduler import replan_on_failure
 
 from .common import Table, write_bench_json
@@ -74,6 +75,22 @@ TRACE = [
 ]
 
 
+def trace_event(ctl: FleetController, kind: str, payload) -> Event:
+    """The controller event for one TRACE entry; a ``fail`` kills the
+    named DAG's last VM in ``ctl``'s current state."""
+    if kind == "arrive":
+        name, maker, w, p, demand = payload
+        return DagArrive(name, MAKERS[maker](), weight=w, priority=p,
+                         max_rate=demand)
+    if kind == "depart":
+        return DagDepart(payload)
+    if kind == "rate":
+        return RateChange(*payload)
+    if kind == "grow":
+        return VmAdd(payload)
+    return VmFail(ctl.entry(payload).schedule.vms[-1].id)
+
+
 def _replay_trace(lib, validate: bool) -> float:
     """Replay the whole TRACE through a fresh controller and return the
     summed apply() wall time — the validate-overhead probe (the verifier's
@@ -82,19 +99,7 @@ def _replay_trace(lib, validate: bool) -> float:
                           step=STEP, max_rate=MAX_RATE, validate=validate)
     total = 0.0
     for kind, payload in TRACE:
-        if kind == "arrive":
-            name, maker, w, p, demand = payload
-            event = DagArrive(name, MAKERS[maker](), weight=w, priority=p,
-                              max_rate=demand)
-        elif kind == "depart":
-            event = DagDepart(payload)
-        elif kind == "rate":
-            event = RateChange(*payload)
-        elif kind == "grow":
-            event = VmAdd(payload)
-        else:
-            event = VmFail(ctl.entry(payload).schedule.vms[-1].id)
-        total += ctl.apply(event).replan_latency_s
+        total += ctl.apply(trace_event(ctl, kind, payload)).replan_latency_s
     return total
 
 
@@ -123,33 +128,27 @@ def run() -> dict:
                  "inc_moved", "full_diff", "full_redeploy", "untouched"])
     rows = []
     for i, (kind, payload) in enumerate(TRACE):
+        # a fail kills the DAG's LAST VM (typically the partial-bundle
+        # one); the baseline repair below kills its own schedule's last VM
+        event = trace_event(ctl, kind, payload)
         if kind == "arrive":
             name, maker, w, p, demand = payload
-            event = DagArrive(name, MAKERS[maker](), weight=w, priority=p,
-                              max_rate=demand)
             dags[name] = MAKERS[maker]()
             weights[name], prios[name] = w, p
             if demand is not None:
                 caps[name] = demand
         elif kind == "depart":
-            event = DagDepart(payload)
             del dags[payload], weights[payload], prios[payload]
             caps.pop(payload, None)
             prev_full.pop(payload, None)
         elif kind == "rate":
             name, ceiling = payload
-            event = RateChange(name, ceiling)
             if ceiling is None:
                 caps.pop(name, None)
             else:
                 caps[name] = ceiling
         elif kind == "grow":
-            event = VmAdd(payload)
             budget += payload
-        else:                                   # fail
-            # kill the DAG's LAST VM (typically the partial-bundle one);
-            # the baseline repair below kills its own schedule's last VM
-            event = VmFail(ctl.entry(payload).schedule.vms[-1].id)
 
         record = ctl.apply(event)
         inc_s = record.replan_latency_s

@@ -11,6 +11,8 @@ from __future__ import annotations
 import json
 import sys
 
+from repro.jaxenv import init_compile_cache
+
 from . import (bench_app_dags, bench_chaos, bench_fleet, bench_latency,
                bench_mapper_search, bench_micro_dags, bench_obs,
                bench_online, bench_optimized, bench_perfmodels,
@@ -39,6 +41,7 @@ BENCHES = [
 
 
 def main() -> None:
+    init_compile_cache()
     if "--smoke" in sys.argv[1:]:
         # CI smoke runs with the repro.analysis verifier on: every plan the
         # smokes build is integrity-checked before it is simulated

@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import (diamond_dag, fleet_resource_surfaces, linear_dag,
                         paper_library, plan_fleet, star_dag, traffic_dag)
+from repro.jaxenv import init_compile_cache
 
 BUDGET = 32
 
@@ -62,4 +63,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

@@ -21,6 +21,7 @@ import numpy as np
 from repro.core import (RoutingPolicy, diamond_dag, linear_dag,
                         paper_library, plan_fleet, simulate_fleet, star_dag,
                         traffic_dag)
+from repro.jaxenv import init_compile_cache
 
 BUDGET = 32
 
@@ -65,4 +66,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

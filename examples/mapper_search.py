@@ -19,6 +19,7 @@ import numpy as np
 from repro.core import (RoutingPolicy, diamond_dag, linear_dag,
                         paper_library, plan, plan_fleet, search_mapping)
 from repro.core.simulator import scan_kernel_cache_stats
+from repro.jaxenv import init_compile_cache
 
 
 def main() -> None:
@@ -57,4 +58,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

@@ -14,6 +14,7 @@ Run:  PYTHONPATH=src python examples/online_controller.py
 from repro.core import (DagArrive, DagDepart, EventTrace, FleetController,
                         RateChange, RoutingPolicy, VmAdd, VmFail,
                         diamond_dag, linear_dag, paper_library, star_dag)
+from repro.jaxenv import init_compile_cache
 
 
 def main() -> None:
@@ -54,4 +55,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

@@ -8,6 +8,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 
 from repro.core import (DataflowSimulator, diamond_dag, paper_library, plan)
+from repro.jaxenv import init_compile_cache
 from repro.runtime import StreamExecutor
 
 TARGET_RATE = 100.0  # tuples/sec the dataflow must sustain
@@ -44,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 
 from repro.core import RoutingPolicy, paper_library, plan, traffic_dag
+from repro.jaxenv import init_compile_cache
 from repro.runtime import StreamExecutor
 
 
@@ -33,4 +34,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

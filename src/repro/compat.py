@@ -1,97 +1,50 @@
-"""Version compatibility shims for the jax mesh/sharding API.
+"""Thin wrappers over the jax mesh/sharding API (jax 0.9).
 
-The repo targets the post-0.5 explicit-sharding API (``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)``, ``AbstractMesh(shape, names)``) but
-must degrade gracefully on the pinned jax 0.4.x, where ``AxisType`` does not
-exist, ``jax.make_mesh`` takes no ``axis_types`` keyword, and ``AbstractMesh``
-is constructed from ``(name, size)`` pairs.
-
-Everything mesh-shaped in this repo goes through these helpers so the version
-split lives in exactly one module.
+Everything mesh-shaped in this repo goes through these helpers, so the
+repo's calls to the explicit-sharding API (``jax.sharding.AxisType``,
+``jax.make_mesh(..., axis_types=...)``, ``AbstractMesh(shape, names)``,
+``jax.shard_map``) live in one module.
 """
 
 from __future__ import annotations
 
-import enum
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-try:  # jax >= 0.5: real axis types
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    HAS_AXIS_TYPES = True
-except ImportError:  # jax 0.4.x: axis types do not exist; every axis is Auto
-    HAS_AXIS_TYPES = False
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stand-in for ``jax.sharding.AxisType`` on jax 0.4.x.
-
-        Only the *names* matter to callers (they always request Auto); the
-        0.4.x mesh has no notion of per-axis sharding mode, so the value is
-        accepted and dropped by :func:`make_mesh`.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+__all__ = ["AxisType", "make_mesh", "make_abstract_mesh",
+           "default_axis_types", "cost_analysis", "shard_map"]
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              *, axis_types: Optional[Sequence["AxisType"]] = None,
+              *, axis_types: Optional[Sequence[AxisType]] = None,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` that accepts ``axis_types`` on every jax version.
-
-    On jax 0.4.x the ``axis_types`` argument is dropped (the implicit
-    behaviour there matches Auto, which is the only mode this repo uses).
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES and axis_types is not None:
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 axis_types=tuple(axis_types), **kwargs)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` from sequences, with optional axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(tuple(axis_types)
+                                     if axis_types is not None else None),
+                         devices=devices)
 
 
 def make_abstract_mesh(axis_shapes: Sequence[int],
                        axis_names: Sequence[str]) -> AbstractMesh:
-    """Version-portable ``AbstractMesh`` from parallel shape/name sequences."""
-    try:  # jax >= 0.5 signature: AbstractMesh(shape, names)
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:  # jax 0.4.x signature: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    """``AbstractMesh`` from parallel shape/name sequences."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
 
-def default_axis_types(n: int) -> Tuple["AxisType", ...]:
+def default_axis_types(n: int) -> Tuple[AxisType, ...]:
     """``(AxisType.Auto,) * n`` — the repo-wide default for every mesh."""
     return (AxisType.Auto,) * n
 
 
 def cost_analysis(compiled) -> dict:
-    """Per-device cost dict from a compiled executable on any jax version.
-
-    jax 0.4.x returns a one-element list of dicts; newer jax returns the
-    dict directly (and may return None for trivial programs).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """Per-device cost dict of a compiled executable ({} for trivial
+    programs, where jax returns None)."""
+    return compiled.cost_analysis() or {}
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on new jax; ``jax.experimental.shard_map`` on 0.4.x.
-
-    ``check_vma`` maps onto the old API's ``check_rep`` (same meaning: verify
-    the replication/varying-axes accounting of outputs).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with the repo's keyword-only calling convention."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

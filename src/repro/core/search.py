@@ -222,6 +222,25 @@ def _hops_flat(gi: GroupIndex) -> np.ndarray:
     return np.concatenate(parts) if parts else np.zeros(0, dtype=float)
 
 
+def shape_buckets(gis: Sequence[GroupIndex]
+                  ) -> Dict[Tuple[Tuple[int, ...], int], List[int]]:
+    """Candidate indices per compiled shape bucket, keyed by the per-row
+    group counts and the slot count, each padded to a power of two."""
+    buckets: Dict[Tuple[Tuple[int, ...], int], List[int]] = {}
+    for i, gi in enumerate(gis):
+        counts = tuple(hi - lo for lo, hi in gi.row_slices())
+        pad_counts = tuple(_next_pow2(c) if c else 0 for c in counts)
+        key = (pad_counts, _next_pow2(len(gi.slots)))
+        buckets.setdefault(key, []).append(i)
+    return buckets
+
+
+def bucket_row_slices(pad_counts: Sequence[int]) -> List[Tuple[int, int]]:
+    """Per-row ``(lo, hi)`` group spans of a bucket's padded layout."""
+    offs = np.concatenate([[0], np.cumsum(pad_counts)]).astype(int)
+    return [(int(offs[r]), int(offs[r + 1])) for r in range(len(pad_counts))]
+
+
 def evaluate_candidates(dag: Dataflow, alloc: Allocation,
                         mappings: Sequence[ThreadMapping],
                         models: ModelLibrary,
@@ -260,8 +279,7 @@ def evaluate_candidates(dag: Dataflow, alloc: Allocation,
             bucket_sizes[:] = [1] * len(mappings)
         return out
 
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from ..jaxenv import x64
 
     if gis is None:
         gis = [build_group_index(dag, alloc, m, models, policy)
@@ -278,21 +296,13 @@ def evaluate_candidates(dag: Dataflow, alloc: Allocation,
     sample_times = np.arange(0, steps, sample_every) * dt
     window = max(steps - s0, 1) * dt
 
-    buckets: Dict[Tuple, List[int]] = {}
-    for i, gi in enumerate(gis):
-        counts = tuple(hi - lo for lo, hi in gi.row_slices())
-        pad_counts = tuple(_next_pow2(c) if c else 0 for c in counts)
-        key = (pad_counts, _next_pow2(len(gi.slots)))
-        buckets.setdefault(key, []).append(i)
-
+    buckets = shape_buckets(gis)
     raws: List[Optional[SweepRaw]] = [None] * len(gis)
     if bucket_sizes is not None:
         bucket_sizes[:] = [len(v) for v in buckets.values()]
     for (pad_counts, s_pad), idxs in buckets.items():
-        offs = np.concatenate([[0], np.cumsum(pad_counts)]).astype(int)
-        row_slices = [(int(offs[r]), int(offs[r + 1]))
-                      for r in range(len(pad_counts))]
-        g_pad = int(offs[-1])
+        row_slices = bucket_row_slices(pad_counts)
+        g_pad = row_slices[-1][1] if row_slices else 0
         C = len(idxs)
         caps_b = np.zeros((C, g_pad, K))
         frac_b = np.zeros((C, g_pad))
@@ -305,7 +315,7 @@ def evaluate_candidates(dag: Dataflow, alloc: Allocation,
                                              cpu_penalty=cpu_penalty)
             dsts = []
             for r, (lo, hi) in enumerate(gi.row_slices()):
-                dst = offs[r] + np.arange(hi - lo)
+                dst = row_slices[r][0] + np.arange(hi - lo)
                 dsts.append(dst)
                 caps_b[j, dst, :] = caps[lo:hi]
                 frac_b[j, dst] = gi.g_frac[lo:hi]
@@ -315,12 +325,9 @@ def evaluate_candidates(dag: Dataflow, alloc: Allocation,
             hops_b[j] = _hops_flat(gi)
         fn = get_scan_kernel(row_slices, in_edges, [sink_rows], s_pad,
                              batched=True)
-        with enable_x64():
+        with x64():
             q, busy, srv, realized, lat = fn(
-                jnp.asarray(caps_b), jnp.asarray(src_rate),
-                jnp.asarray(dt, dtype=jnp.float64),
-                jnp.asarray(frac_b), jnp.asarray(slot_b),
-                jnp.asarray(hops_b),
+                caps_b, src_rate, np.float64(dt), frac_b, slot_b, hops_b,
                 steps=steps, sample_every=sample_every, s0=s0)
         q, busy, srv, realized, lat = (np.asarray(q), np.asarray(busy),
                                        np.asarray(srv), np.asarray(realized),

@@ -554,18 +554,18 @@ class SweepBatch:
     # -- the jitted lax.scan kernel -------------------------------------------
     def _run_scan(self, caps: np.ndarray, src_rate: np.ndarray, steps: int,
                   sample_every: int, s0: int, dt: float):
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from ..jaxenv import x64
         spec = self.spec
         fn = get_scan_kernel(spec.row_slices, spec.in_edges,
                              spec.sink_groups, len(spec.slots))
-        with enable_x64():
+        # host arrays carry their dtypes; the jitted call converts them
+        # inside the x64 scope, so none of them narrows to float32
+        f64 = np.float64
+        with x64():
             queues, busy, served, realized, lat = fn(
-                jnp.asarray(caps), jnp.asarray(src_rate),
-                jnp.asarray(dt, dtype=jnp.float64),
-                jnp.asarray(spec.g_frac, dtype=jnp.float64),
-                jnp.asarray(spec.g_slot, dtype=jnp.int32),
-                jnp.asarray(self._hops_flat, dtype=jnp.float64),
+                caps.astype(f64), src_rate.astype(f64), f64(dt),
+                spec.g_frac.astype(f64), spec.g_slot.astype(np.int32),
+                np.asarray(self._hops_flat, dtype=f64),
                 steps=steps, sample_every=sample_every, s0=s0)
         return (np.asarray(queues), np.asarray(busy), np.asarray(served),
                 np.asarray(realized), np.asarray(lat))

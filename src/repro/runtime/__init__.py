@@ -3,7 +3,7 @@ enacts a planned Schedule on real JAX devices (the "Storm" substrate of the
 reproduction), deterministic fault injection, and the live enactment layer
 mirroring FleetController deltas onto running executors."""
 
-from .operators import OPERATORS, make_operator
+from .operators import OPERATORS
 from .stream import MicroBatch, SyntheticSource, VirtualClock, WallClock
 from .chaos import (Fault, FaultEvent, FaultInjector, FaultKind, FaultPlan,
                     FaultTimeline, InjectedOperatorError, null_injector)

@@ -18,8 +18,10 @@ instances:
   operator, surviving slots keep theirs.
 
 After each event the fleet runs a short measurement window per live DAG
-(on the shared clock — a :class:`~repro.runtime.stream.VirtualClock` by
-default, so replays are deterministic and sleep-free).  Faults from the
+on the shared clock: a :class:`~repro.runtime.stream.WallClock` by default,
+so operator busy time is the device's; pass a
+:class:`~repro.runtime.stream.VirtualClock` for deterministic, sleep-free
+replays priced from the model tables.  Faults from the
 :class:`~repro.runtime.chaos.FaultPlan` fire during those windows; when
 the executor's circuit breaker trips a VM, :meth:`apply` feeds the
 synthetic :class:`~repro.core.online.VmFail` back into the controller,
@@ -50,7 +52,7 @@ from ..core.scheduler import Schedule
 from .chaos import FaultInjector, FaultPlan, FaultTimeline
 from .executor import (ExecutionReport, RebindInfo, RobustnessPolicy,
                        StreamExecutor)
-from .stream import VirtualClock
+from .stream import WallClock
 
 TruthArg = Union[None, ModelLibrary, Mapping[str, ModelLibrary]]
 
@@ -179,7 +181,7 @@ class LiveFleet:
         self.ctl = controller
         self.plan_faults = (fault_plan if fault_plan is not None
                             else FaultPlan.none())
-        self.clock = clock if clock is not None else VirtualClock()
+        self.clock = clock if clock is not None else WallClock()
         self.truth = truth
         self.robustness = robustness
         self.frames_per_event = int(frames_per_event)
@@ -269,8 +271,9 @@ class LiveFleet:
 
         The fleet's clock is installed as the telemetry clock for the
         whole tick, so spans recorded anywhere below (controller replans,
-        rebinds, executor windows) carry virtual timestamps and two
-        replays of one chaos seed produce bit-identical traces."""
+        rebinds, executor windows) carry the fleet's timestamps; under a
+        virtual clock two replays of one chaos seed produce bit-identical
+        traces."""
         with _obs_clock.use_clock(self.clock), \
                 _obs_span("fleet.tick", kind=type(event).__name__):
             return self._apply(event, at)

@@ -1,13 +1,17 @@
 """Streaming executor: enacts a planned Schedule on real JAX devices.
 
-Each resource *slot* of the schedule is pinned to a JAX device (slot k ->
-``jax.devices()[k % n]``; with ``--xla_force_host_platform_device_count`` the
-CPU exposes many devices, so a multi-VM schedule demonstrably runs with the
-same thread->slot structure the mapper produced).  Tuples flow as micro-batch
-frames in DAG topological order; at each task the frame is routed over the
-task's per-slot thread groups (shuffle = thread-proportional, slot-aware =
-capacity-proportional), processed by the slot-pinned jitted operator, and the
-results interleave downstream — the Storm execution model of §2.
+Each resource *slot* of the schedule is pinned to a JAX device, round-robin
+over the executor's device list (slot k -> ``devices[k % n]``, default
+``jax.devices()``; with ``--xla_force_host_platform_device_count`` the CPU
+exposes many devices, so a multi-VM schedule demonstrably runs with the
+same thread->slot structure the mapper produced).  Every routed part is
+placed on its slot's device with ``jax.device_put`` before the slot's
+jitted operator runs, so the operator executes where its input lives.
+Tuples flow as micro-batch frames in DAG topological order; at each task
+the frame is routed over the task's per-slot thread groups (shuffle =
+thread-proportional, slot-aware = capacity-proportional), processed by the
+slot-pinned jitted operator, and the results interleave downstream — the
+Storm execution model of §2.
 
 Robustness machinery (the chaos-hardened enactment layer):
 
@@ -42,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import defaultdict
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -117,14 +121,16 @@ class StreamExecutor:
     ``models`` — pass the *actual* measured profile to emulate a cluster
     whose reality drifted from the planner's tables); ``faults`` injects a
     :class:`~repro.runtime.chaos.FaultPlan` slice; ``robustness`` tunes the
-    retry/watchdog/shedding/breaker machinery.
+    retry/watchdog/shedding/breaker machinery; ``devices`` is the list
+    slots are pinned to round-robin (default ``jax.devices()``).
     """
 
     def __init__(self, schedule: Schedule, models: ModelLibrary,
                  *, policy: RoutingPolicy = RoutingPolicy.SHUFFLE,
                  faults: Optional[FaultInjector] = None,
                  robustness: Optional[RobustnessPolicy] = None,
-                 clock=None, truth: Optional[ModelLibrary] = None):
+                 clock=None, truth: Optional[ModelLibrary] = None,
+                 devices: Optional[Sequence[jax.Device]] = None):
         self.schedule = schedule
         self.models = models
         self.truth = truth if truth is not None else models
@@ -134,20 +140,23 @@ class StreamExecutor:
         self.clock = clock if clock is not None else WallClock()
         self.dag = schedule.dag
         self.groups = slot_groups(schedule.mapping, schedule.allocation)
-        self._devices = jax.devices()
+        self._devices = list(devices) if devices is not None \
+            else jax.devices()
+        if not self._devices:
+            raise ValueError("StreamExecutor needs at least one device")
         self._device_counter = 0
         # slot -> device pinning (stable order over VMs then slots)
         self.slot_device = {}
         for slot in schedule.mapping.slots():
             self.slot_device[slot] = self._next_device()
-        # jitted operator per (task, slot)
+        # jitted operator per (task, slot); it runs on the device its
+        # input was placed on (see _invoke_part)
         self._ops = {}
         for task, g in self.groups.items():
             kind = schedule.allocation.tasks[task].kind
             fn = OPERATORS[kind]
             for slot in g:
-                dev = self.slot_device[slot]
-                self._ops[(task, slot)] = jax.jit(fn, device=dev)  # lint: ok JAX101 - one-time __init__ cache, each (task, slot) jitted once
+                self._ops[(task, slot)] = jax.jit(fn)  # lint: ok JAX101 - one-time __init__ cache, each (task, slot) jitted once
         self._frame_count = defaultdict(int)
         # robustness state (survives rebinds for surviving slots)
         self._consecutive_failures: Dict = defaultdict(int)
@@ -161,6 +170,9 @@ class StreamExecutor:
         #: frames consumed across ALL runs — the fault plan's frame axis
         #: continues across measurement windows (chaos determinism)
         self.frames_seen = 0
+        #: sink name -> output arrays of the last frame that reached the
+        #: sinks (what the dataflow emitted; compared across device sets)
+        self.last_sink_outputs: Dict[str, Dict[str, jax.Array]] = {}
 
     # -- device bookkeeping ----------------------------------------------------
     def _next_device(self):
@@ -226,7 +238,7 @@ class StreamExecutor:
                     info.transplanted[old_slot] = slot
                     restarted.add(slot)
                     continue
-                self._ops[key] = jax.jit(fn, device=self.slot_device[slot])  # lint: ok JAX101 - rebind jits each new (task, slot) once
+                self._ops[key] = jax.jit(fn)  # lint: ok JAX101 - rebind jits each new (task, slot) once
                 info.fresh_ops += 1
                 restarted.add(slot)
         info.kept_slots = sorted(kept, key=lambda s: (s.vm, s.slot))
@@ -294,7 +306,9 @@ class StreamExecutor:
                      deadline_at: float) -> Optional[Dict[str, jax.Array]]:
         """One routed part through retry/backoff, fault injection, and the
         circuit breaker.  Returns the operator output, or None when the
-        part was lost (exhausted retries / tripped VM)."""
+        part was lost (exhausted retries / tripped VM).  Only the modelled
+        operator failure (:class:`InjectedOperatorError`) is retried; a
+        JAX or XLA error (compile, out of memory, lost device) propagates."""
         n = next(iter(part.values())).shape[0]
         fail_attempts = 0
         slow = 1.0
@@ -308,6 +322,7 @@ class StreamExecutor:
                                      max(0.0, deadline_at - self.clock.now())
                                      + 1e-9))
         op = self._ops[(task, slot)]
+        part = jax.device_put(part, self.slot_device[slot])
         for attempt in range(self.robust.max_retries + 1):
             if self.clock.now() > deadline_at:
                 raise _FrameTimeout(f"frame {frame_seq} exceeded its "
@@ -319,7 +334,8 @@ class StreamExecutor:
                         if not self.faults.is_crashed(slot.vm)
                         else FaultKind.VM_CRASH, task)
                 t0 = time.perf_counter()
-                out = op(part)
+                # wait for the device: busy is its time, not the enqueue's
+                out = jax.block_until_ready(op(part))
                 busy = time.perf_counter() - t0
                 if self.clock.virtual:
                     busy = self._virtual_cost(task, slot, n)
@@ -334,9 +350,7 @@ class StreamExecutor:
                 acc[0] += n
                 acc[1] += busy
                 return out
-            except _FrameTimeout:
-                raise
-            except Exception:
+            except InjectedOperatorError:
                 if attempt >= self.robust.max_retries:
                     break
                 self._run_counters["retries"] = \
@@ -447,10 +461,12 @@ class StreamExecutor:
                 self._run_counters.get("frames_timed_out", 0) + 1
             return "timeout", None
         # block on one sink output to get a truthful completion time
+        self.last_sink_outputs = {}
         for snk in self.dag.sinks():
             out = outputs.get(snk.name)
             if out:
                 jax.block_until_ready(next(iter(out.values())))
+                self.last_sink_outputs[snk.name] = out
         if self._run_counters.pop("frame_lost_tuples", None):
             self._run_counters["frames_failed"] = \
                 self._run_counters.get("frames_failed", 0) + 1

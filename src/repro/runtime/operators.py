@@ -3,7 +3,8 @@
 Each operator consumes a micro-batch of tuples — a dict of arrays whose
 leading axis is the tuple axis — and emits a micro-batch.  The JAX bodies are
 jit-compiled once per (operator, batch shape) and run on the device backing
-the resource slot the scheduler mapped the operator's threads to.
+the resource slot the scheduler mapped the operator's threads to (the
+executor places each input there before the call).
 
 These mirror the profiler's single-tuple Python bodies (repro.core.profiler)
 but vectorized: the executor processes tuples in micro-batches, which is also
@@ -12,7 +13,6 @@ how a TPU-resident DSPS would amortize dispatch.
 
 from __future__ import annotations
 
-import functools
 from typing import Callable, Dict
 
 import jax
@@ -84,9 +84,3 @@ OPERATORS: Dict[str, Callable[[Batch], Batch]] = {
 
 #: host-side service latency (s) injected per micro-batch for external tasks
 SERVICE_LATENCY = {"azure_blob": 0.010, "azure_table": 0.005}
-
-
-def make_operator(kind: str, device: "jax.Device") -> Callable[[Batch], Batch]:
-    """Jit the operator body pinned to ``device`` (the mapped slot)."""
-    fn = OPERATORS[kind]
-    return jax.jit(fn, device=device)

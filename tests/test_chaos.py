@@ -245,3 +245,43 @@ def test_rate_error_and_drift_detection(lib):
     assert alerts[0].dag == "d1"
     assert alerts[0].predicted_stable and not alerts[0].measured_stable
     assert detect_drift({"d1": False}, {"d1": rep_bad}) == []
+
+
+def test_device_error_propagates_instead_of_counting_lost_tuples(lib):
+    """Only the modelled InjectedOperatorError is retried: any other error
+    from an operator (a compile, memory or device fault) ends the run
+    instead of being counted as lost tuples."""
+    schedule = plan(diamond_dag(), 80, lib, allocator="mba", mapper="sam")
+    ex = StreamExecutor(schedule, lib, clock=VirtualClock())
+    key = next(iter(ex._ops))
+
+    def broken(part):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    ex._ops[key] = broken
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        ex.run(80, n_frames=4, batch=16)
+
+
+def test_executor_pins_slots_to_the_callers_devices(lib):
+    """Slots round-robin over the given device list; every routed part
+    runs there and the sink outputs live there."""
+    import jax
+    dev = jax.devices()[-1]
+    schedule = plan(diamond_dag(), 80, lib, allocator="mba", mapper="sam")
+    ex = StreamExecutor(schedule, lib, clock=VirtualClock(), devices=[dev])
+    rep = ex.run(80, n_frames=4, batch=16, warmup_frames=1)
+    assert set(ex.slot_device.values()) == {dev}
+    assert set(rep.device_frame_counts) == {str(dev)}
+    assert ex.last_sink_outputs
+    for arrays in ex.last_sink_outputs.values():
+        for v in arrays.values():
+            assert v.devices() == {dev}
+    with pytest.raises(ValueError, match="at least one device"):
+        StreamExecutor(schedule, lib, devices=[])
+
+
+def test_live_fleet_defaults_to_the_wall_clock(lib):
+    from repro.runtime import WallClock
+    fleet = LiveFleet(_controller(lib), frames_per_event=0)
+    assert isinstance(fleet.clock, WallClock)
