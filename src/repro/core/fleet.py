@@ -88,6 +88,7 @@ from .predictor import (GroupIndex, ResourcePrediction, ResourceSweep,
 from .routing import RoutingPolicy
 from .scheduler import Schedule, plan
 from .simulator import DataflowSimulator, SimResult, SweepBatch
+from ..obs.trace import span as _obs_span
 from ..obs.trace import trace as _obs_trace
 
 ModelsArg = Union[ModelLibrary, Mapping[str, ModelLibrary]]
@@ -1090,59 +1091,67 @@ def simulate_fleet(fleet: FleetPlan, models: ModelsArg, *,
     if not runnable:
         raise ValueError("fleet plan has no mapped DAGs to simulate "
                          "(was it planned with mapper=None?)")
-    sims = [DataflowSimulator(e.dag, e.schedule.allocation,
-                              e.schedule.mapping, _models_for(models, e.name),
-                              policy=policy, cpu_penalty=cpu_penalty,
-                              gi=(e.group_index if reuse_group_index
-                                  and policy is fleet.policy else None))
-            for e in runnable]
-    batch = SweepBatch(sims)
-    omegas_list = [fracs * e.omega for e in runnable]
-    raw = batch.sweep_raw(omegas_list, duration=duration, dt=dt,
-                          warmup=warmup,
-                          latency_sample_every=latency_sample_every,
-                          engine=engine)
-    results = batch.results_from_raw(omegas_list, raw)
-
-    entries: Dict[str, FleetSimEntry] = {}
-    vm_cpu_p: Dict[int, float] = {}
-    vm_mem_p: Dict[int, float] = {}
-    vm_cpu_a: Dict[int, float] = {}
-    vm_mem_a: Dict[int, float] = {}
-    for i, (e, sim) in enumerate(zip(runnable, sims)):
-        gi = sim.gi
-        stable = [r.omega for r in results[i] if r.stable]
-        entries[e.name] = FleetSimEntry(
-            name=e.name, omega_planned=e.omega,
-            omegas=np.asarray(omegas_list[i]), results=results[i],
-            predicted_max_rate=predict_max_rate_gi(gi),
-            actual_max_stable=max(stable) if stable else 0.0)
-        # §8.5.2 prediction at the SAME operating point the actuals are
-        # measured at (fracs[k1] of the planned rate), under the study's
-        # policy — so predicted-vs-actual never mixes operating points even
-        # when ``fractions`` excludes 1.0
-        pred = predict_resources_sweep(gi, [float(fracs[k1]) * e.omega],
-                                       mapping=e.schedule.mapping).at(0)
-        for vm, c in pred.vm_cpu.items():
-            vm_cpu_p[vm] = vm_cpu_p.get(vm, 0.0) + c
-        for vm, m in pred.vm_mem.items():
-            vm_mem_p[vm] = vm_mem_p.get(vm, 0.0) + m
-        # actual draw from the co-simulated served rates at fraction k1:
-        # proportional C/M scale-down on each group's mean served rate
-        g_lo, g_hi = batch.group_spans[i]
-        served_rate = raw.served[g_lo:g_hi, k1] / raw.window
-        frac_used = np.where(gi.g_cap > 0,
-                             np.minimum(1.0, served_rate /
-                                        np.where(gi.g_cap > 0, gi.g_cap, 1.0)),
-                             1.0)
-        for g in range(gi.n_groups):
-            vm = gi.slots[int(gi.g_slot[g])].vm
-            vm_cpu_a[vm] = vm_cpu_a.get(vm, 0.0) + gi.g_cpu[g] * frac_used[g]
-            vm_mem_a[vm] = vm_mem_a.get(vm, 0.0) + gi.g_mem[g] * frac_used[g]
-    slot_busy = {s: float(raw.busy[j, k1] / raw.window)
-                 for j, s in enumerate(batch.spec.slots)}
-    return FleetSimReport(
-        fractions=fracs, at_fraction=float(fracs[k1]), entries=entries,
-        skipped=skipped, vm_cpu_predicted=vm_cpu_p, vm_mem_predicted=vm_mem_p,
-        vm_cpu_actual=vm_cpu_a, vm_mem_actual=vm_mem_a, slot_busy=slot_busy,
-        policy=policy, engine=engine)
+    with _obs_span("cosim", dags=len(runnable)):
+        with _obs_span("cosim.build"):
+            sims = [DataflowSimulator(
+                e.dag, e.schedule.allocation, e.schedule.mapping,
+                _models_for(models, e.name), policy=policy,
+                cpu_penalty=cpu_penalty,
+                gi=(e.group_index if reuse_group_index
+                    and policy is fleet.policy else None))
+                for e in runnable]
+            batch = SweepBatch(sims)
+        omegas_list = [fracs * e.omega for e in runnable]
+        raw = batch.sweep_raw(omegas_list, duration=duration, dt=dt,
+                              warmup=warmup,
+                              latency_sample_every=latency_sample_every,
+                              engine=engine)
+        with _obs_span("cosim.results"):
+            results = batch.results_from_raw(omegas_list, raw)
+        with _obs_span("cosim.resources"):
+            entries: Dict[str, FleetSimEntry] = {}
+            vm_cpu_p: Dict[int, float] = {}
+            vm_mem_p: Dict[int, float] = {}
+            vm_cpu_a: Dict[int, float] = {}
+            vm_mem_a: Dict[int, float] = {}
+            for i, (e, sim) in enumerate(zip(runnable, sims)):
+                gi = sim.gi
+                stable = [r.omega for r in results[i] if r.stable]
+                entries[e.name] = FleetSimEntry(
+                    name=e.name, omega_planned=e.omega,
+                    omegas=np.asarray(omegas_list[i]), results=results[i],
+                    predicted_max_rate=predict_max_rate_gi(gi),
+                    actual_max_stable=max(stable) if stable else 0.0)
+                # §8.5.2 prediction at the SAME operating point the actuals
+                # are measured at (fracs[k1] of the planned rate), under the
+                # study's policy — so predicted-vs-actual never mixes
+                # operating points even when ``fractions`` excludes 1.0
+                pred = predict_resources_sweep(
+                    gi, [float(fracs[k1]) * e.omega],
+                    mapping=e.schedule.mapping).at(0)
+                for vm, c in pred.vm_cpu.items():
+                    vm_cpu_p[vm] = vm_cpu_p.get(vm, 0.0) + c
+                for vm, m in pred.vm_mem.items():
+                    vm_mem_p[vm] = vm_mem_p.get(vm, 0.0) + m
+                # actual draw from the co-simulated served rates at fraction
+                # k1: proportional C/M scale-down on each group's mean
+                # served rate
+                g_lo, g_hi = batch.group_spans[i]
+                served_rate = raw.served[g_lo:g_hi, k1] / raw.window
+                cap = np.where(gi.g_cap > 0, gi.g_cap, 1.0)
+                frac_used = np.where(gi.g_cap > 0,
+                                     np.minimum(1.0, served_rate / cap), 1.0)
+                for g in range(gi.n_groups):
+                    vm = gi.slots[int(gi.g_slot[g])].vm
+                    vm_cpu_a[vm] = (vm_cpu_a.get(vm, 0.0)
+                                    + gi.g_cpu[g] * frac_used[g])
+                    vm_mem_a[vm] = (vm_mem_a.get(vm, 0.0)
+                                    + gi.g_mem[g] * frac_used[g])
+            slot_busy = {s: float(raw.busy[j, k1] / raw.window)
+                         for j, s in enumerate(batch.spec.slots)}
+        return FleetSimReport(
+            fractions=fracs, at_fraction=float(fracs[k1]), entries=entries,
+            skipped=skipped, vm_cpu_predicted=vm_cpu_p,
+            vm_mem_predicted=vm_mem_p, vm_cpu_actual=vm_cpu_a,
+            vm_mem_actual=vm_mem_a, slot_busy=slot_busy, policy=policy,
+            engine=engine)

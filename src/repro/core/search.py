@@ -67,7 +67,7 @@ from .perfmodel import ModelLibrary
 from .predictor import (GroupIndex, build_group_index,
                         effective_capacity_matrix, predict_max_rate_gi)
 from .routing import RoutingPolicy
-from ..obs.trace import trace as _obs_trace
+from ..obs.trace import span as _obs_span
 from .simulator import (STABLE_SLOPE_PER_S, DataflowSimulator, SweepRaw,
                         _slope_columns, _sweep_steps, edge_hop_latencies,
                         get_scan_kernel)
@@ -279,67 +279,73 @@ def evaluate_candidates(dag: Dataflow, alloc: Allocation,
             bucket_sizes[:] = [1] * len(mappings)
         return out
 
+    import jax
+
     from ..jaxenv import x64
 
     if gis is None:
-        gis = [build_group_index(dag, alloc, m, models, policy)
-               for m in mappings]
+        with _obs_span("search.index"):
+            gis = [build_group_index(dag, alloc, m, models, policy)
+                   for m in mappings]
     if not gis:
         return []
-    steps, sample_every, s0 = _sweep_steps(duration, dt, warmup,
-                                           latency_sample_every)
-    K = len(omegas)
-    gi0 = gis[0]
-    src_rate = gi0.betas[:, None] * omegas[None, :]     # shared: same DAG
-    in_edges = gi0.in_edges
-    sink_rows = [gi0.task_of[t.name] for t in dag.sinks()]
-    sample_times = np.arange(0, steps, sample_every) * dt
-    window = max(steps - s0, 1) * dt
-
-    buckets = shape_buckets(gis)
+    with _obs_span("search.pack"):
+        steps, sample_every, s0 = _sweep_steps(duration, dt, warmup,
+                                               latency_sample_every)
+        K = len(omegas)
+        gi0 = gis[0]
+        src_rate = gi0.betas[:, None] * omegas[None, :]  # shared: same DAG
+        in_edges = gi0.in_edges
+        sink_rows = [gi0.task_of[t.name] for t in dag.sinks()]
+        sample_times = np.arange(0, steps, sample_every) * dt
+        window = max(steps - s0, 1) * dt
+        buckets = shape_buckets(gis)
     raws: List[Optional[SweepRaw]] = [None] * len(gis)
     if bucket_sizes is not None:
         bucket_sizes[:] = [len(v) for v in buckets.values()]
     for (pad_counts, s_pad), idxs in buckets.items():
-        row_slices = bucket_row_slices(pad_counts)
-        g_pad = row_slices[-1][1] if row_slices else 0
-        C = len(idxs)
-        caps_b = np.zeros((C, g_pad, K))
-        frac_b = np.zeros((C, g_pad))
-        slot_b = np.zeros((C, g_pad), dtype=np.int32)
-        hops_b = np.zeros((C, sum(len(e) for e in in_edges)))
-        real_idx: List[np.ndarray] = []
-        for j, i in enumerate(idxs):
-            gi = gis[i]
-            caps = effective_capacity_matrix(gi, omegas,
-                                             cpu_penalty=cpu_penalty)
-            dsts = []
-            for r, (lo, hi) in enumerate(gi.row_slices()):
-                dst = row_slices[r][0] + np.arange(hi - lo)
-                dsts.append(dst)
-                caps_b[j, dst, :] = caps[lo:hi]
-                frac_b[j, dst] = gi.g_frac[lo:hi]
-                slot_b[j, dst] = gi.g_slot[lo:hi]
-            real_idx.append(np.concatenate(dsts).astype(int) if dsts
-                            else np.zeros(0, dtype=int))
-            hops_b[j] = _hops_flat(gi)
-        fn = get_scan_kernel(row_slices, in_edges, [sink_rows], s_pad,
-                             batched=True)
-        with x64():
-            q, busy, srv, realized, lat = fn(
-                caps_b, src_rate, np.float64(dt), frac_b, slot_b, hops_b,
-                steps=steps, sample_every=sample_every, s0=s0)
-        q, busy, srv, realized, lat = (np.asarray(q), np.asarray(busy),
-                                       np.asarray(srv), np.asarray(realized),
-                                       np.asarray(lat))
-        for j, i in enumerate(idxs):
-            ri = real_idx[j]
-            n_slots = len(gis[i].slots)
-            raws[i] = SweepRaw(
-                queues=q[j][ri], busy=busy[j][:n_slots], served=srv[j][ri],
-                realized=realized[j], latency=lat[j],
-                sample_times=sample_times, steps=steps, s0=s0, dt=dt,
-                window=window)
+        with _obs_span("search.pack"):
+            row_slices = bucket_row_slices(pad_counts)
+            g_pad = row_slices[-1][1] if row_slices else 0
+            C = len(idxs)
+            caps_b = np.zeros((C, g_pad, K))
+            frac_b = np.zeros((C, g_pad))
+            slot_b = np.zeros((C, g_pad), dtype=np.int32)
+            hops_b = np.zeros((C, sum(len(e) for e in in_edges)))
+            real_idx: List[np.ndarray] = []
+            for j, i in enumerate(idxs):
+                gi = gis[i]
+                caps = effective_capacity_matrix(gi, omegas,
+                                                 cpu_penalty=cpu_penalty)
+                dsts = []
+                for r, (lo, hi) in enumerate(gi.row_slices()):
+                    dst = row_slices[r][0] + np.arange(hi - lo)
+                    dsts.append(dst)
+                    caps_b[j, dst, :] = caps[lo:hi]
+                    frac_b[j, dst] = gi.g_frac[lo:hi]
+                    slot_b[j, dst] = gi.g_slot[lo:hi]
+                real_idx.append(np.concatenate(dsts).astype(int) if dsts
+                                else np.zeros(0, dtype=int))
+                hops_b[j] = _hops_flat(gi)
+        with _obs_span("search.launch"):
+            fn = get_scan_kernel(row_slices, in_edges, [sink_rows], s_pad,
+                                 batched=True)
+            with x64():
+                out = fn(caps_b, src_rate, np.float64(dt), frac_b, slot_b,
+                         hops_b, steps=steps, sample_every=sample_every,
+                         s0=s0)
+        with _obs_span("search.wait"):
+            jax.block_until_ready(out)
+        with _obs_span("search.fetch"):
+            q, busy, srv, realized, lat = (np.asarray(a) for a in out)
+            for j, i in enumerate(idxs):
+                ri = real_idx[j]
+                n_slots = len(gis[i].slots)
+                raws[i] = SweepRaw(
+                    queues=q[j][ri], busy=busy[j][:n_slots],
+                    served=srv[j][ri], realized=realized[j], latency=lat[j],
+                    sample_times=sample_times, steps=steps, s0=s0, dt=dt,
+                    window=window)
     return raws  # type: ignore[return-value]
 
 
@@ -362,7 +368,6 @@ def _judge_raw(raw: SweepRaw) -> Tuple[np.ndarray, np.ndarray]:
 # The search.
 # ---------------------------------------------------------------------------
 
-@_obs_trace("search_mapping")
 def search_mapping(dag: Dataflow, omega: float, models: ModelLibrary, *,
                    allocator: str = "mba",
                    allocation: Optional[Allocation] = None,
@@ -399,6 +404,59 @@ def search_mapping(dag: Dataflow, omega: float, models: ModelLibrary, *,
     validated to map exactly this allocation's threads onto the search
     pool's VMs, then deduped and move-seeded like any base candidate.
     """
+    with _obs_span("search_mapping", seed=int(seed)):
+        with _obs_span("search.pool"):
+            alloc, pool, base_maps = _search_pool(
+                dag, omega, models, allocator, allocation, vms, vm_sizes,
+                grow_pool, max_extra_slots, include, extra_candidates)
+        with _obs_span("search.candidates"):
+            cands = generate_candidates(
+                dag, alloc, pool, models, rsm_weights=rsm_weights,
+                n_moves=n_moves, seed=seed, include=include,
+                base_mappings=base_maps, extra_mappings=extra_candidates)
+        if not cands:
+            raise InsufficientResourcesError(
+                "<pool>", "no candidate mapping packs the search pool")
+        fracs = np.asarray(rate_fractions, dtype=float) \
+            if rate_fractions is not None else np.linspace(0.5, 1.5, 11)
+        omegas = omega * fracs
+        with _obs_span("search.index"):
+            gis = [build_group_index(dag, alloc, c.mapping, models, policy)
+                   for c in cands]
+        bucket_sizes: List[int] = []
+        raws = evaluate_candidates(
+            dag, alloc, [c.mapping for c in cands], models, omegas,
+            policy=policy, cpu_penalty=cpu_penalty, duration=duration, dt=dt,
+            warmup=warmup, latency_sample_every=latency_sample_every,
+            engine=engine, gis=gis, bucket_sizes=bucket_sizes)
+        with _obs_span("search.judge"):
+            results: List[CandidateResult] = []
+            for cand, gi, raw in zip(cands, gis, raws):
+                stable, slopes = _judge_raw(raw)
+                ok = omegas[stable]
+                results.append(CandidateResult(
+                    name=cand.name, mapping=cand.mapping, omegas=omegas,
+                    stable=stable, latency_slope=slopes,
+                    max_stable_rate=float(ok.max()) if ok.size else 0.0,
+                    predicted_max_rate=float(predict_max_rate_gi(gi)),
+                    used_slots=len(gi.slots)))
+            results.sort(key=lambda c: (-c.max_stable_rate, c.used_slots,
+                                        c.name))
+            return RankedCandidates(
+                dag=dag.name, omega=float(omega), allocator=allocator,
+                policy=policy, omegas=omegas, vms=pool, engine=engine,
+                candidates=results, bucket_sizes=bucket_sizes)
+
+
+def _search_pool(dag: Dataflow, omega: float, models: ModelLibrary,
+                 allocator: str, allocation: Optional[Allocation],
+                 vms: Optional[Sequence[VM]], vm_sizes: Sequence[int],
+                 grow_pool: bool, max_extra_slots: int,
+                 include: Sequence[str],
+                 extra_candidates: Optional[Dict[str, ThreadMapping]]
+                 ) -> Tuple[Allocation, List[VM], Dict[str, ThreadMapping]]:
+    """The allocation, the VM pool every candidate shares, and the base
+    mappers' mappings on it (see :func:`search_mapping`)."""
     alloc = allocation if allocation is not None \
         else ALLOCATORS[allocator](dag, omega, models)
     pool = list(vms) if vms is not None else acquire_vms(alloc.slots,
@@ -440,37 +498,4 @@ def search_mapping(dag: Dataflow, omega: float, models: ModelLibrary, *,
                 raise ValueError(
                     f"extra candidate {name!r} uses VMs outside the "
                     "search pool")
-    cands = generate_candidates(dag, alloc, pool, models,
-                                rsm_weights=rsm_weights, n_moves=n_moves,
-                                seed=seed, include=include,
-                                base_mappings=base_maps,
-                                extra_mappings=extra_candidates)
-    if not cands:
-        raise InsufficientResourcesError(
-            "<pool>", "no candidate mapping packs the search pool")
-    fracs = np.asarray(rate_fractions, dtype=float) \
-        if rate_fractions is not None else np.linspace(0.5, 1.5, 11)
-    omegas = omega * fracs
-    gis = [build_group_index(dag, alloc, c.mapping, models, policy)
-           for c in cands]
-    bucket_sizes: List[int] = []
-    raws = evaluate_candidates(
-        dag, alloc, [c.mapping for c in cands], models, omegas,
-        policy=policy, cpu_penalty=cpu_penalty, duration=duration, dt=dt,
-        warmup=warmup, latency_sample_every=latency_sample_every,
-        engine=engine, gis=gis, bucket_sizes=bucket_sizes)
-    results: List[CandidateResult] = []
-    for cand, gi, raw in zip(cands, gis, raws):
-        stable, slopes = _judge_raw(raw)
-        ok = omegas[stable]
-        results.append(CandidateResult(
-            name=cand.name, mapping=cand.mapping, omegas=omegas,
-            stable=stable, latency_slope=slopes,
-            max_stable_rate=float(ok.max()) if ok.size else 0.0,
-            predicted_max_rate=float(predict_max_rate_gi(gi)),
-            used_slots=len(gi.slots)))
-    results.sort(key=lambda c: (-c.max_stable_rate, c.used_slots, c.name))
-    return RankedCandidates(
-        dag=dag.name, omega=float(omega), allocator=allocator, policy=policy,
-        omegas=omegas, vms=pool, engine=engine, candidates=results,
-        bucket_sizes=bucket_sizes)
+    return alloc, pool, base_maps

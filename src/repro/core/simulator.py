@@ -472,12 +472,14 @@ class SweepBatch:
         K = len(omegas[0])
         if any(len(w) != K for w in omegas):
             raise ValueError("all sweeps must share one rate-grid length")
-        caps = np.concatenate([
-            effective_capacity_matrix(sim.gi, w, cpu_penalty=sim.cpu_penalty)
-            for sim, w in zip(self.sims, omegas)], axis=0)
-        src_rate = np.concatenate([
-            sim.gi.betas[:, None] * w[None, :]
-            for sim, w in zip(self.sims, omegas)], axis=0)
+        with _obs_span("cosim.inputs"):
+            caps = np.concatenate([
+                effective_capacity_matrix(sim.gi, w,
+                                          cpu_penalty=sim.cpu_penalty)
+                for sim, w in zip(self.sims, omegas)], axis=0)
+            src_rate = np.concatenate([
+                sim.gi.betas[:, None] * w[None, :]
+                for sim, w in zip(self.sims, omegas)], axis=0)
         steps, sample_every, s0 = _sweep_steps(duration, dt, warmup,
                                                latency_sample_every)
         if engine == "scan":
@@ -554,21 +556,27 @@ class SweepBatch:
     # -- the jitted lax.scan kernel -------------------------------------------
     def _run_scan(self, caps: np.ndarray, src_rate: np.ndarray, steps: int,
                   sample_every: int, s0: int, dt: float):
+        import jax
+
         from ..jaxenv import x64
         spec = self.spec
-        fn = get_scan_kernel(spec.row_slices, spec.in_edges,
-                             spec.sink_groups, len(spec.slots))
         # host arrays carry their dtypes; the jitted call converts them
         # inside the x64 scope, so none of them narrows to float32
         f64 = np.float64
-        with x64():
-            queues, busy, served, realized, lat = fn(
-                caps.astype(f64), src_rate.astype(f64), f64(dt),
-                spec.g_frac.astype(f64), spec.g_slot.astype(np.int32),
-                np.asarray(self._hops_flat, dtype=f64),
-                steps=steps, sample_every=sample_every, s0=s0)
-        return (np.asarray(queues), np.asarray(busy), np.asarray(served),
-                np.asarray(realized), np.asarray(lat))
+        with _obs_span("cosim.inputs"):
+            args = (caps.astype(f64), src_rate.astype(f64), f64(dt),
+                    spec.g_frac.astype(f64), spec.g_slot.astype(np.int32),
+                    np.asarray(self._hops_flat, dtype=f64))
+        with _obs_span("cosim.launch"):
+            fn = get_scan_kernel(spec.row_slices, spec.in_edges,
+                                 spec.sink_groups, len(spec.slots))
+            with x64():
+                out = fn(*args, steps=steps, sample_every=sample_every,
+                         s0=s0)
+        with _obs_span("cosim.wait"):
+            jax.block_until_ready(out)
+        with _obs_span("cosim.fetch"):
+            return tuple(np.asarray(a) for a in out)
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,8 @@ from .export import export_tracer, read_jsonl, write_chrome, write_jsonl
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, REGISTRY,
                       bridge_controller_log, counter, disable_metrics,
                       enable_metrics, gauge, histogram, metrics_enabled,
-                      observe_controller_record, observe_execution_report,
-                      prometheus_text, register_collector, reset_metrics,
-                      snapshot)
+                      observe_controller_record, prometheus_text,
+                      register_collector, reset_metrics, snapshot)
 from .scoreboard import Residual, ResidualStats, Sample, Scoreboard
 from .trace import (SpanRecord, Tracer, disable_tracing, enable_tracing,
                     get_tracer, set_tracer, span, trace, tracing_enabled)
@@ -39,7 +38,7 @@ __all__ = [
     "counter", "gauge", "histogram", "enable_metrics", "disable_metrics",
     "metrics_enabled", "register_collector", "prometheus_text", "snapshot",
     "reset_metrics", "observe_controller_record", "bridge_controller_log",
-    "observe_execution_report", "metrics",
+    "metrics",
     # scoreboard
     "Sample", "Residual", "ResidualStats", "Scoreboard",
     # export

@@ -28,7 +28,6 @@ __all__ = [
     "counter", "gauge", "histogram", "enable_metrics", "disable_metrics",
     "metrics_enabled", "register_collector", "prometheus_text", "snapshot",
     "reset_metrics", "observe_controller_record", "bridge_controller_log",
-    "observe_execution_report",
 ]
 
 LabelPairs = Tuple[Tuple[str, str], ...]
@@ -403,32 +402,6 @@ def observe_controller_record(record: Any) -> None:
     if getattr(record, "recalibrated", False):
         counter("repro_auto_recalibrations_total",
                 "Automatic model recalibrations enacted.").inc()
-
-
-def observe_execution_report(report: Any) -> None:
-    """Publish one ExecutionReport's robustness counters as metrics."""
-    if not REGISTRY.enabled:
-        return
-    counter("repro_frames_total",
-            "Micro-batch frames processed by executors.",
-            ).inc(int(report.frames))
-    counter("repro_frames_shed_total",
-            "Frames dropped by load shedding.").inc(int(report.frames_shed))
-    counter("repro_frames_retried_total",
-            "Operator invocations retried after transient errors.",
-            ).inc(int(report.retries))
-    counter("repro_frames_timed_out_total",
-            "Frames killed by the frame-deadline watchdog.",
-            ).inc(int(report.frames_timed_out))
-    counter("repro_frames_failed_total",
-            "Frames that lost tuples past retry.",
-            ).inc(int(report.frames_failed))
-    counter("repro_tuples_lost_total",
-            "Tuples lost to failures and shedding.",
-            ).inc(int(report.tuples_lost))
-    histogram("repro_measured_latency_seconds",
-              "Mean end-to-end frame latency per measurement window.",
-              unit="s").observe(float(report.mean_latency))
 
 
 def bridge_controller_log(log: Any) -> int:

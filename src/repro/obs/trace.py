@@ -11,12 +11,20 @@ the instrumentation hook used throughout the planner/controller/runtime;
 when the tracer is disabled it returns a shared no-op span object without
 touching any lock, so dormant instrumentation costs one attribute check
 per call site.
+
+While tracing is enabled every span also opens a
+``jax.profiler.TraceAnnotation`` under its name (attributes stay in the
+:class:`SpanRecord`), so a profiler trace shows the program's spans on
+the host, on the same clock as the device's programs.  The profiler is
+imported on the first span opened while enabled, and only once ``jax``
+is already imported: this module itself stays stdlib-only.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import sys
 import threading
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, TypeVar
 
@@ -81,11 +89,30 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, once a span has found ``jax`` imported
+_ANNOTATION: Any = None
+
+
+def _annotation(name: str) -> Any:
+    """An open profiler annotation named ``name``, or None before ``jax``
+    is imported."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    mark = _ANNOTATION(name)
+    mark.__enter__()
+    return mark
+
 
 class _Span:
-    """A live span; closing it appends a :class:`SpanRecord` to the tracer."""
+    """A live span; closing it appends a :class:`SpanRecord` to the tracer
+    and closes its profiler annotation."""
 
-    __slots__ = ("_tracer", "name", "_attrs", "_t0", "_depth", "_closed")
+    __slots__ = ("_tracer", "name", "_attrs", "_t0", "_depth", "_closed",
+                 "_mark")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Dict[str, Any]) -> None:
@@ -95,6 +122,7 @@ class _Span:
         self._t0 = 0.0
         self._depth = 0
         self._closed = False
+        self._mark: Any = None
 
     def set(self, **attrs: Any) -> "_Span":
         """Attach attributes after opening (e.g. results known at close)."""
@@ -103,11 +131,14 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self._depth = self._tracer._push(self.name)
+        self._mark = _annotation(self.name)
         self._t0 = _clock.now()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         t1 = _clock.now()
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
         self._closed = True
         self._tracer._pop(self, t1)
         return None

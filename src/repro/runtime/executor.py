@@ -39,6 +39,12 @@ which makes whole chaos replays deterministic and sleep-free.
 
 Measured per-(task, slot-group) service rates accumulate in the executor
 and feed :mod:`repro.core.calibrate` — the measure→recalibrate loop.
+
+Telemetry (:mod:`repro.obs`, free while off): :meth:`process_frame` opens
+an ``executor.frame`` span with one child span per stage of the frame
+(``route``, ``place``, ``launch``, ``wait``, ``service``, ``gather``,
+``sink_wait``), and counts frames and tuples at the sites that decide their
+fate.
 """
 
 from __future__ import annotations
@@ -62,6 +68,22 @@ from ..obs.trace import span as _obs_span
 from .chaos import FaultInjector, FaultKind, InjectedOperatorError
 from .operators import OPERATORS, SERVICE_LATENCY
 from .stream import MicroBatch, SyntheticSource, VirtualClock, WallClock
+
+_FRAMES = _obs_metrics.counter(
+    "repro_frames_total", "Micro-batch frames processed by executors.")
+_FRAMES_SHED = _obs_metrics.counter(
+    "repro_frames_shed_total", "Frames dropped by load shedding.")
+_FRAMES_RETRIED = _obs_metrics.counter(
+    "repro_frames_retried_total",
+    "Operator invocations retried after transient errors.")
+_FRAMES_TIMED_OUT = _obs_metrics.counter(
+    "repro_frames_timed_out_total",
+    "Frames killed by the frame-deadline watchdog.")
+_FRAMES_FAILED = _obs_metrics.counter(
+    "repro_frames_failed_total", "Frames that lost tuples past retry.")
+_TUPLES_LOST = _obs_metrics.counter(
+    "repro_tuples_lost_total",
+    "Tuples of parts lost past retry or skipped on a tripped VM.")
 
 
 @dataclasses.dataclass
@@ -322,7 +344,8 @@ class StreamExecutor:
                                      max(0.0, deadline_at - self.clock.now())
                                      + 1e-9))
         op = self._ops[(task, slot)]
-        part = jax.device_put(part, self.slot_device[slot])
+        with _obs_span("executor.place"):
+            part = jax.device_put(part, self.slot_device[slot])
         for attempt in range(self.robust.max_retries + 1):
             if self.clock.now() > deadline_at:
                 raise _FrameTimeout(f"frame {frame_seq} exceeded its "
@@ -334,8 +357,11 @@ class StreamExecutor:
                         if not self.faults.is_crashed(slot.vm)
                         else FaultKind.VM_CRASH, task)
                 t0 = time.perf_counter()
+                with _obs_span("executor.launch"):
+                    out = op(part)
                 # wait for the device: busy is its time, not the enqueue's
-                out = jax.block_until_ready(op(part))
+                with _obs_span("executor.wait"):
+                    out = jax.block_until_ready(out)
                 busy = time.perf_counter() - t0
                 if self.clock.virtual:
                     busy = self._virtual_cost(task, slot, n)
@@ -355,10 +381,12 @@ class StreamExecutor:
                     break
                 self._run_counters["retries"] = \
                     self._run_counters.get("retries", 0) + 1
+                _FRAMES_RETRIED.inc()
                 self.clock.sleep(self.robust.backoff_base * (2 ** attempt))
         # retries exhausted: part lost; feed the breaker
         self._run_counters["tuples_lost"] = \
             self._run_counters.get("tuples_lost", 0) + n
+        _TUPLES_LOST.inc(n)
         self._consecutive_failures[slot] += 1
         if (self._consecutive_failures[slot] >= self.robust.breaker_threshold
                 and slot.vm not in self.tripped_vms):
@@ -374,12 +402,13 @@ class StreamExecutor:
             return arrays
         kind = self.schedule.allocation.tasks[task].kind
         n = next(iter(arrays.values())).shape[0]
-        weights = self._weights(task)
-        # split the frame over slot groups
-        cuts, acc = [], 0.0
-        for _, f in weights[:-1]:
-            acc += f
-            cuts.append(int(round(acc * n)))
+        with _obs_span("executor.route"):
+            weights = self._weights(task)
+            # split the frame over slot groups
+            cuts, acc = [], 0.0
+            for _, f in weights[:-1]:
+                acc += f
+                cuts.append(int(round(acc * n)))
         parts = {}
         lo = 0
         lost = False
@@ -389,10 +418,12 @@ class StreamExecutor:
                     # breaker open: skip the dead VM's share entirely
                     self._run_counters["tuples_lost"] = \
                         self._run_counters.get("tuples_lost", 0) + (hi - lo)
+                    _TUPLES_LOST.inc(hi - lo)
                     lost = True
                     lo = hi
                     continue
-                part = {k: v[lo:hi] for k, v in arrays.items()}
+                with _obs_span("executor.route"):
+                    part = {k: v[lo:hi] for k, v in arrays.items()}
                 out = self._invoke_part(task, slot, part, frame_seq,
                                         deadline_at)
                 if out is None:
@@ -406,7 +437,8 @@ class StreamExecutor:
         if kind in SERVICE_LATENCY:
             # external service wait, parallelized over the task's threads
             q_total = sum(g.values())
-            self.clock.sleep(SERVICE_LATENCY[kind] / max(1, q_total))
+            with _obs_span("executor.service"):
+                self.clock.sleep(SERVICE_LATENCY[kind] / max(1, q_total))
         outs = list(parts.values())
         if not outs:
             return arrays if not lost else {}
@@ -414,10 +446,12 @@ class StreamExecutor:
             return outs[0]
         # interleave across slots: gather to one device (the real tuple
         # movement between slots that Storm's network transfer performs)
-        home = self.slot_device[next(iter(parts))]
-        keys = outs[0].keys()
-        return {k: jnp.concatenate([jax.device_put(o[k], home) for o in outs],
-                                   axis=0) for k in keys}
+        with _obs_span("executor.gather"):
+            home = self.slot_device[next(iter(parts))]
+            keys = outs[0].keys()
+            return {k: jnp.concatenate([jax.device_put(o[k], home)
+                                        for o in outs], axis=0)
+                    for k in keys}
 
     def process_frame(self, frame: MicroBatch, interval: float
                       ) -> Tuple[str, Optional[float]]:
@@ -425,11 +459,18 @@ class StreamExecutor:
         stack.  Returns ``(status, latency)`` with status one of ``"ok"``,
         ``"shed"``, ``"timeout"``, ``"failed"``; latency is set for ok
         frames only."""
+        _FRAMES.inc()
+        with _obs_span("executor.frame", seq=frame.seq):
+            return self._process_frame(frame, interval)
+
+    def _process_frame(self, frame: MicroBatch, interval: float
+                       ) -> Tuple[str, Optional[float]]:
         now = self.clock.now()
         if interval > 0 and (now - frame.created) > \
                 self.robust.shed_backlog_frames * interval:
             self._run_counters["frames_shed"] = \
                 self._run_counters.get("frames_shed", 0) + 1
+            _FRAMES_SHED.inc()
             return "shed", None
         if self.faults is not None:
             self.faults.crashed_vms(frame.seq,
@@ -437,6 +478,7 @@ class StreamExecutor:
             if self.faults.drop_frame(frame.seq):
                 self._run_counters["frames_shed"] = \
                     self._run_counters.get("frames_shed", 0) + 1
+                _FRAMES_SHED.inc()
                 return "shed", None
         deadline_at = (now + self.robust.frame_deadline_intervals * interval
                        if interval > 0 else float("inf"))
@@ -459,17 +501,20 @@ class StreamExecutor:
         except _FrameTimeout:
             self._run_counters["frames_timed_out"] = \
                 self._run_counters.get("frames_timed_out", 0) + 1
+            _FRAMES_TIMED_OUT.inc()
             return "timeout", None
         # block on one sink output to get a truthful completion time
         self.last_sink_outputs = {}
         for snk in self.dag.sinks():
             out = outputs.get(snk.name)
             if out:
-                jax.block_until_ready(next(iter(out.values())))
+                with _obs_span("executor.sink_wait"):
+                    jax.block_until_ready(next(iter(out.values())))
                 self.last_sink_outputs[snk.name] = out
         if self._run_counters.pop("frame_lost_tuples", None):
             self._run_counters["frames_failed"] = \
                 self._run_counters.get("frames_failed", 0) + 1
+            _FRAMES_FAILED.inc()
             return "failed", None
         return "ok", self.clock.now() - frame.created
 
@@ -478,12 +523,9 @@ class StreamExecutor:
             n_frames: Optional[int] = None, seed: int = 0) -> ExecutionReport:
         with _obs_span("executor.run", dag=self.schedule.dag.name,
                        omega=float(omega)):
-            report = self._run(omega, duration=duration, batch=batch,
-                               warmup_frames=warmup_frames,
-                               n_frames=n_frames, seed=seed)
-        if _obs_metrics.REGISTRY.enabled:
-            _obs_metrics.observe_execution_report(report)
-        return report
+            return self._run(omega, duration=duration, batch=batch,
+                             warmup_frames=warmup_frames,
+                             n_frames=n_frames, seed=seed)
 
     def _run(self, omega: float, *, duration: float = 2.0,
              batch: int = 32, warmup_frames: int = 2,

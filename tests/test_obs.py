@@ -7,6 +7,8 @@ timelines are *bit*-identical across replays of the same chaos seed.
 
 import json
 import math
+import os
+import pathlib
 import time
 from types import SimpleNamespace
 
@@ -24,8 +26,9 @@ from repro.obs import (MetricsRegistry, Scoreboard, SpanRecord, Tracer,
                        observe_controller_record)
 from repro.obs.clock import use_clock
 from repro.obs.scoreboard import MEASURED, PLANNED, SIMULATED
-from repro.obs.trace import spans_from_jsonl, spans_to_chrome
+from repro.obs.trace import _NULL_SPAN, spans_from_jsonl, spans_to_chrome
 from repro.runtime import FaultPlan, LiveFleet, VirtualClock
+from repro.runtime.chaos import Fault, FaultKind
 
 BUDGET = 24
 
@@ -603,3 +606,183 @@ def test_write_bench_json_envelope(tmp_path):
                                     "cpu_count"}
     assert isinstance(on_disk["git_sha"], str) and on_disk["git_sha"]
     assert on_disk["created_unix_s"] > 0
+
+
+# -- spans in the profiler trace, and the layers' spans and counters ---------
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; the host events of every line as
+    ``(name, start_ns, end_ns)``, in order of start."""
+    import jax
+    from jax.profiler import ProfileData
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    events = [(e.name, int(e.start_ns), int(e.end_ns))
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host")
+              for line in plane.lines for e in line.events]
+    return sorted(events, key=lambda e: e[1])
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def test_enabled_span_reaches_the_profiler_trace_nested(fresh_obs, tmp_path):
+    def work():
+        with obs.span("obs.outer", dag="d"):
+            with obs.span("obs.inner"):
+                time.sleep(0.001)
+
+    events = _profiled(tmp_path, work)
+    (outer,) = _named(events, "obs.outer")
+    (inner,) = _named(events, "obs.inner")
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    # the name alone reaches the trace; the attributes stay in the record
+    rec = {s.name: s for s in fresh_obs.spans}
+    assert rec["obs.outer"].attr_dict() == {"dag": "d"}
+    assert rec["obs.inner"].depth == rec["obs.outer"].depth + 1
+
+
+def test_disabled_span_never_reaches_the_profiler_trace(tmp_path):
+    obs.disable()
+    spans = []
+
+    def work():
+        with obs.span("obs.dormant") as s:
+            spans.append(s)
+
+    events = _profiled(tmp_path, work)
+    assert spans == [_NULL_SPAN]
+    assert not _named(events, "obs.dormant")
+
+
+def test_obs_imports_and_traces_without_jax():
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "import repro.obs as o\n"
+            "o.enable()\n"
+            "with o.span('x'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'repro.obs imported jax'\n"
+            "print(len(o.get_tracer().spans))\n")
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env={**os.environ, "PYTHONPATH": src},
+                         timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "1"
+
+
+def _executor(lib, **kwargs):
+    from repro.core import plan
+    from repro.runtime import StreamExecutor
+    from repro.runtime.stream import SyntheticSource
+    # at 120 tuples/s one task's threads span two slots: a gather
+    schedule = plan(diamond_dag(), 120, lib, allocator="mba", mapper="sam")
+    ex = StreamExecutor(schedule, lib, clock=VirtualClock(), **kwargs)
+    source = SyntheticSource(120, batch=16, seed=3, clock=VirtualClock())
+    return ex, list(source.frames(n_frames=2))
+
+
+STAGES = ("executor.route", "executor.place", "executor.launch",
+          "executor.wait", "executor.service", "executor.gather",
+          "executor.sink_wait")
+
+
+def test_process_frame_records_the_executor_span_tree(lib, fresh_obs):
+    ex, frames = _executor(lib)
+    assert ex.process_frame(frames[0], 0.0)[0] == "ok"
+    spans = fresh_obs.spans
+    (root,) = [s for s in spans if s.name == "executor.frame"]
+    assert root.attr_dict() == {"seq": frames[0].seq}
+    kids = sorted((s for s in spans if s.depth == root.depth + 1),
+                  key=lambda s: s.t0)
+    assert all(root.t0 <= s.t0 and s.t1 <= root.t1 for s in kids)
+    names = [s.name for s in kids]
+    assert set(names) == set(STAGES)
+    assert names[0] == "executor.route" and names[-1] == "executor.sink_wait"
+    # every part is placed, launched, then waited for
+    for i, name in enumerate(names):
+        if name == "executor.launch":
+            assert names[i - 1] == "executor.place"
+            assert names[i + 1] == "executor.wait"
+
+
+def test_process_frame_spans_reach_the_profiler_trace(lib, fresh_obs,
+                                                     tmp_path):
+    ex, frames = _executor(lib)
+    ex.process_frame(frames[0], 0.0)      # compiles every program first
+    events = _profiled(tmp_path, lambda: ex.process_frame(frames[1], 0.0))
+    (root,) = _named(events, "executor.frame")
+    traced = [e[0] for e in events
+              if e[0] in STAGES and root[1] <= e[1] and e[2] <= root[2]]
+    (rec,) = [s for s in fresh_obs.spans if s.name == "executor.frame"
+              and s.attr_dict() == {"seq": frames[1].seq}]
+    recorded = [s.name for s in sorted(fresh_obs.spans, key=lambda s: s.t0)
+                if s.name in STAGES and rec.t0 <= s.t0 and s.t1 <= rec.t1]
+    # every recorded stage, and nothing else, is in the trace, in order
+    assert set(traced) == set(STAGES)
+    assert traced == recorded
+
+
+@pytest.mark.parametrize("fault, status, counter", [
+    (Fault(FaultKind.OPERATOR_ERROR, frame=0, dag="d", task="p", count=3),
+     "failed", "repro_frames_failed_total"),
+    (Fault(FaultKind.DROP_FRAME, frame=0, dag="d"),
+     "shed", "repro_frames_shed_total"),
+])
+def test_process_frame_counts_frames_without_run(lib, fresh_obs, fault,
+                                                 status, counter):
+    from repro.runtime import FaultInjector
+    ex, frames = _executor(lib, faults=FaultInjector(
+        FaultPlan(faults=(fault,)), "d"))
+    frames[0].seq = 0
+    assert ex.process_frame(frames[0], 0.0)[0] == status
+    snap = obs.snapshot()
+    assert snap["repro_frames_total"]["value"] == 1
+    assert snap[counter]["value"] == 1
+    if status == "failed":
+        # every part of the task fails past its retries
+        assert snap["repro_frames_retried_total"]["value"] == \
+            ex._run_counters["retries"] > 0
+        assert snap["repro_tuples_lost_total"]["value"] == \
+            ex._run_counters["tuples_lost"] == frames[0].size
+
+
+def _cosimulate(lib):
+    from repro.core import plan_fleet, simulate_fleet
+    fp = plan_fleet({"linear": linear_dag(), "diamond": diamond_dag()}, lib,
+                    budget_slots=12)
+    simulate_fleet(fp, lib, duration=2.0, dt=0.1, engine="scan")
+
+
+def _search(lib):
+    from repro.core.search import search_mapping
+    search_mapping(diamond_dag(), 100, lib, n_moves=2, rate_fractions=[1.0],
+                   duration=1.0, dt=0.5, seed=5)
+
+
+@pytest.mark.parametrize("call, root, children", [
+    (_cosimulate, "cosim",
+     ("cosim.build", "cosim.inputs", "cosim.launch", "cosim.wait",
+      "cosim.fetch", "cosim.results", "cosim.resources")),
+    (_search, "search_mapping",
+     ("search.pool", "search.candidates", "search.index", "search.pack",
+      "search.launch", "search.wait", "search.fetch", "search.judge")),
+])
+def test_cosimulation_and_search_record_every_stage(lib, fresh_obs, call,
+                                                     root, children):
+    call(lib)
+    spans = fresh_obs.spans
+    (top,) = [s for s in spans if s.name == root]
+    kids = [s for s in spans if s.depth == top.depth + 1
+            and top.t0 <= s.t0 and s.t1 <= top.t1]
+    assert {s.name for s in kids} == set(children)
