@@ -11,7 +11,9 @@ Tuples flow as micro-batch frames in DAG topological order; at each task
 the frame is routed over the task's per-slot thread groups (shuffle =
 thread-proportional, slot-aware = capacity-proportional), processed by the
 slot-pinned jitted operator, and the results interleave downstream — the
-Storm execution model of §2.
+Storm execution model of §2.  A device frame cut into two or more parts is
+cut by one compiled program, and the parts' outputs are joined by another,
+so a task's route costs one launch each way whatever its keys and parts.
 
 Robustness machinery (the chaos-hardened enactment layer):
 
@@ -43,13 +45,15 @@ and feed :mod:`repro.core.calibrate` — the measure→recalibrate loop.
 Telemetry (:mod:`repro.obs`, free while off): :meth:`process_frame` opens
 an ``executor.frame`` span with one child span per stage of the frame
 (``route``, ``place``, ``launch``, ``wait``, ``service``, ``gather``,
-``sink_wait``), and counts frames and tuples at the sites that decide their
-fate.
+``sink_wait``), counts frames and tuples at the sites that decide their
+fate, and counts the compiled split and interleave launches
+(``repro_executor_route_launches_total``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -84,6 +88,28 @@ _FRAMES_FAILED = _obs_metrics.counter(
 _TUPLES_LOST = _obs_metrics.counter(
     "repro_tuples_lost_total",
     "Tuples of parts lost past retry or skipped on a tripped VM.")
+_ROUTE_LAUNCHES = {
+    stage: _obs_metrics.counter(
+        "repro_executor_route_launches_total",
+        "Compiled programs launched to split a frame over a task's slot "
+        "groups or to interleave their outputs.", labels={"stage": stage})
+    for stage in ("split", "interleave")}
+
+
+@functools.partial(jax.jit, static_argnames="bounds")
+def _split(arrays: Dict[str, jax.Array],
+           bounds: Tuple[Tuple[int, int], ...]) -> List[Dict[str, jax.Array]]:
+    """Every part ``[lo, hi)`` of a frame in one program (compiled once
+    per cut and frame shape, shared by every executor)."""
+    return [{k: v[lo:hi] for k, v in arrays.items()} for lo, hi in bounds]
+
+
+@jax.jit
+def _interleave(outs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
+    """The parts' outputs joined back along the tuple axis, every key in
+    one program."""
+    return {k: jnp.concatenate([o[k] for o in outs], axis=0)
+            for k in outs[0]}
 
 
 @dataclasses.dataclass
@@ -404,34 +430,41 @@ class StreamExecutor:
         n = next(iter(arrays.values())).shape[0]
         with _obs_span("executor.route"):
             weights = self._weights(task)
-            # split the frame over slot groups
-            cuts, acc = [], 0.0
-            for _, f in weights[:-1]:
+            # split the frame over slot groups: the non-empty parts
+            # [lo, hi), in slot order
+            routed, lo, acc = [], 0, 0.0
+            for i, (slot, f) in enumerate(weights):
                 acc += f
-                cuts.append(int(round(acc * n)))
+                hi = n if i == len(weights) - 1 else int(round(acc * n))
+                if hi > lo:
+                    routed.append((slot, lo, hi))
+                lo = hi
+            bounds = tuple((lo, hi) for _, lo, hi in routed)
+            if len(bounds) > 1 and all(isinstance(v, jax.Array)
+                                       for v in arrays.values()):
+                # a frame on a device: every part in one program
+                _ROUTE_LAUNCHES["split"].inc()
+                pieces = _split(arrays, bounds=bounds)
+            else:
+                # one part is the whole frame; a host frame slices for free
+                pieces = [{k: v[lo:hi] for k, v in arrays.items()}
+                          for lo, hi in bounds]
         parts = {}
-        lo = 0
         lost = False
-        for (slot, _), hi in zip(weights, cuts + [n]):
-            if hi > lo:
-                if slot.vm in self.tripped_vms:
-                    # breaker open: skip the dead VM's share entirely
-                    self._run_counters["tuples_lost"] = \
-                        self._run_counters.get("tuples_lost", 0) + (hi - lo)
-                    _TUPLES_LOST.inc(hi - lo)
-                    lost = True
-                    lo = hi
-                    continue
-                with _obs_span("executor.route"):
-                    part = {k: v[lo:hi] for k, v in arrays.items()}
-                out = self._invoke_part(task, slot, part, frame_seq,
-                                        deadline_at)
-                if out is None:
-                    lost = True
-                else:
-                    parts[slot] = out
-                    self._frame_count[str(self.slot_device[slot])] += 1
-            lo = hi
+        for (slot, lo, hi), part in zip(routed, pieces):
+            if slot.vm in self.tripped_vms:
+                # breaker open: skip the dead VM's share entirely
+                self._run_counters["tuples_lost"] = \
+                    self._run_counters.get("tuples_lost", 0) + (hi - lo)
+                _TUPLES_LOST.inc(hi - lo)
+                lost = True
+                continue
+            out = self._invoke_part(task, slot, part, frame_seq, deadline_at)
+            if out is None:
+                lost = True
+            else:
+                parts[slot] = out
+                self._frame_count[str(self.slot_device[slot])] += 1
         if lost:
             self._run_counters["frame_lost_tuples"] = 1
         if kind in SERVICE_LATENCY:
@@ -448,10 +481,8 @@ class StreamExecutor:
         # movement between slots that Storm's network transfer performs)
         with _obs_span("executor.gather"):
             home = self.slot_device[next(iter(parts))]
-            keys = outs[0].keys()
-            return {k: jnp.concatenate([jax.device_put(o[k], home)
-                                        for o in outs], axis=0)
-                    for k in keys}
+            _ROUTE_LAUNCHES["interleave"].inc()
+            return _interleave(jax.device_put(outs, home))
 
     def process_frame(self, frame: MicroBatch, interval: float
                       ) -> Tuple[str, Optional[float]]:
