@@ -23,8 +23,10 @@ import dataclasses
 import heapq
 import math
 import queue as queue_mod
+import struct
 import threading
 import time
+from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .perfmodel import (ModelLibrary, PerfModel, TrialResult, build_perf_model,
@@ -88,6 +90,128 @@ class BatchFileWrite:
             self.buf.clear()
             self.flushes += 1
         return self.flushes
+
+
+# RIoTBench STATS kinds (arXiv:1606.07621), one tuple a call, over a cycle of
+# SYS records: the single-tuple bodies of ``repro.runtime.operators``' STATS
+# kinds, with the same parameters.
+
+def sys_record(i: int, sensors: int, fields: int, record_bytes: int) -> bytes:
+    """The ``i``-th record of a fixed cycle of SYS records."""
+    obs = [((i * 7919 + 13 * f) % 1000) / 10.0 for f in range(fields)]
+    return struct.pack(f"<II{fields}f", (i * 7919) % sensors, i * 5,
+                       *obs).ljust(record_bytes, b"\0")
+
+
+class SysTrial:
+    """A body that takes the next record of a 4096-record cycle per call."""
+
+    def __init__(self, params) -> None:
+        self.p = params
+        self.records = [sys_record(i, params.SYS_SENSORS, params.SYS_FIELDS,
+                                   params.RECORD_BYTES) for i in range(4096)]
+        self.i = 0
+        self.state: dict = {}
+
+    def next(self):
+        rec = self.records[self.i % len(self.records)]
+        self.i += 1
+        sensor, ts, *obs = struct.unpack_from(f"<II{self.p.SYS_FIELDS}f", rec)
+        return sensor, ts, obs
+
+
+class SenmlParse(SysTrial):
+    def __call__(self):
+        return self.next()
+
+
+class BlockAverage(SysTrial):
+    def __call__(self):
+        sensor, _, obs = self.next()
+        sums, n = self.state.get(sensor, ([0.0] * len(obs), 0))
+        sums = [a + b for a, b in zip(sums, obs)]
+        n += 1
+        if n == self.p.W_AVG:
+            self.state[sensor] = ([0.0] * len(obs), 0)
+            return [a / n for a in sums]
+        self.state[sensor] = (sums, n)
+        return None
+
+
+class KalmanFilter(SysTrial):
+    def __call__(self):
+        sensor, _, obs = self.next()
+        xs, ps = self.state.get(sensor, ([0.0] * len(obs),
+                                         [self.p.KALMAN_P0] * len(obs)))
+        out_x, out_p = [], []
+        for x, p, z in zip(xs, ps, obs):
+            p += self.p.KALMAN_Q
+            k = p / (p + self.p.KALMAN_R)
+            out_x.append(x + k * (z - x))
+            out_p.append((1 - k) * p)
+        self.state[sensor] = (out_x, out_p)
+        return out_x
+
+
+class SlidingLinearRegression(SysTrial):
+    def __call__(self):
+        sensor, ts, obs = self.next()
+        window = self.state.setdefault(sensor, deque(maxlen=self.p.W_SLR))
+        window.append((ts, obs))
+        m = len(window)
+        dt = [t - ts for t, _ in window]
+        tm = sum(dt) / m
+        sxx = sum((d - tm) ** 2 for d in dt)
+        ahead = -min(dt) / max(m - 1, 1)
+        preds = []
+        for f in range(len(obs)):
+            xs = [o[f] for _, o in window]
+            xm = sum(xs) / m
+            sxy = sum((d - tm) * (x - xm) for d, x in zip(dt, xs))
+            slope = sxy / sxx if m >= 2 else 0.0
+            preds.append(xm + slope * (ahead - tm))
+        return preds
+
+
+def fmix32(x: int) -> int:
+    """Murmur3's 32-bit finalizer (``operators.hash32`` on one int)."""
+    x ^= x >> 16
+    x = (x * 0x85EBCA6B) & 0xFFFFFFFF
+    x ^= x >> 13
+    x = (x * 0xC2B2AE35) & 0xFFFFFFFF
+    return x ^ (x >> 16)
+
+
+class DistinctApproxCount(SysTrial):
+    def __call__(self):
+        sensor, _, _ = self.next()
+        bits = self.p.LOGLOG_BITS
+        buckets = self.state.setdefault("buckets", [0] * (1 << bits))
+        h = fmix32(sensor)
+        b, rest = h & ((1 << bits) - 1), h >> bits
+        rank = max(buckets[b], 32 - rest.bit_length() - bits + 1)
+        self.state["sum"] = self.state.get("sum", 0) + rank - buckets[b]
+        buckets[b] = rank
+        return self.p.LOGLOG_ALPHA * len(buckets) * 2.0 ** (
+            self.state["sum"] / len(buckets))
+
+
+class Accumulate(SysTrial):
+    def __call__(self):
+        sensor, _, obs = self.next()
+        branch = self.i % self.p.ACC_BRANCHES
+        window = self.state.setdefault((sensor, branch),
+                                       deque(maxlen=self.p.W_PLOT))
+        window.append(obs + obs + [0.0])
+        return list(window)
+
+
+#: the STATS kinds' single-tuple bodies, by kind
+STATS_BODIES = {"senml_parse": SenmlParse, "average": BlockAverage,
+                "kalman_filter": KalmanFilter,
+                "sliding_linear_regression": SlidingLinearRegression,
+                "distinct_approx_count": DistinctApproxCount,
+                "accumulate": Accumulate}
 
 
 @dataclasses.dataclass
@@ -374,6 +498,9 @@ def profile_task(kind: str, *, live: bool = False,
             "pi": lambda: op_pi,
             "batch_file_write": lambda: BatchFileWrite(),
         }
+        if kind in STATS_BODIES:
+            from ..runtime import operators as params
+            makers[kind] = lambda: STATS_BODIES[kind](params)
         if kind not in makers:
             raise ValueError(f"live profiling unsupported for {kind!r} "
                              "(external service); use analytic")

@@ -15,6 +15,19 @@ Storm execution model of §2.  A device frame cut into two or more parts is
 cut by one compiled program, and the parts' outputs are joined by another,
 so a task's route costs one launch each way whatever its keys and parts.
 
+A stateful kind (``operators.KEYED``) keeps a state table on the device of
+each of its slots, and its tuples are routed by key (Storm's fields
+grouping): tuple i goes to thread ``hash32(key_i) mod Q`` and so to that
+thread's slot, where the whole frame arrives with every other slot's rows
+masked invalid.  Parts keep the frame's shape, so the route compiles once
+whatever the mix of keys; one compiled program finds the owners and cuts
+the parts, and one takes each row back from its owner's output.  A kind
+without a key sends the frame whole to the task's first thread (global
+grouping), and a schedule that maps it to two or more slots is refused.  A kind in ``operators.MERGES`` reads the union of its in-edges'
+outputs, each row tagged with its in-edge (``branch``); every other kind
+reads its first in-edge that produced an output.  :meth:`StreamExecutor.rebind`
+carries each key's state to its new owner.
+
 Robustness machinery (the chaos-hardened enactment layer):
 
 * **per-frame operator retry** — a failing operator attempt is retried with
@@ -44,10 +57,11 @@ and feed :mod:`repro.core.calibrate` — the measure→recalibrate loop.
 
 Telemetry (:mod:`repro.obs`, free while off): :meth:`process_frame` opens
 an ``executor.frame`` span with one child span per stage of the frame
-(``route``, ``place``, ``launch``, ``wait``, ``service``, ``gather``,
-``sink_wait``), counts frames and tuples at the sites that decide their
-fate, and counts the compiled split and interleave launches
-(``repro_executor_route_launches_total``).
+(``route``, ``keyroute``, ``merge``, ``place``, ``launch``, ``wait``,
+``service``, ``gather``, ``sink_wait``), counts frames and tuples at the
+sites that decide their fate, the compiled split and interleave launches
+(``repro_executor_route_launches_total``), the host time of keyed routing,
+and the bytes of state held and moved by rebinds.
 """
 
 from __future__ import annotations
@@ -70,7 +84,7 @@ from ..core.scheduler import Schedule
 from ..obs import metrics as _obs_metrics
 from ..obs.trace import span as _obs_span
 from .chaos import FaultInjector, FaultKind, InjectedOperatorError
-from .operators import OPERATORS, SERVICE_LATENCY
+from .operators import KEYED, MERGES, OPERATORS, SERVICE_LATENCY, hash32
 from .stream import MicroBatch, SyntheticSource, VirtualClock, WallClock
 
 _FRAMES = _obs_metrics.counter(
@@ -94,6 +108,22 @@ _ROUTE_LAUNCHES = {
         "Compiled programs launched to split a frame over a task's slot "
         "groups or to interleave their outputs.", labels={"stage": stage})
     for stage in ("split", "interleave")}
+_KEYROUTE_SECONDS = _obs_metrics.counter(
+    "repro_executor_keyroute_seconds_total",
+    "Host seconds spent finding the owner thread of each tuple of a keyed "
+    "task's frame and cutting the frame into its slots' masked parts.",
+    unit="s")
+_STATE_MOVED = _obs_metrics.counter(
+    "repro_executor_state_moved_bytes_total",
+    "Bytes of keyed state moved to a key's new owner slot by rebinds.",
+    unit="bytes")
+
+
+def _state_gauge(task: str):
+    return _obs_metrics.gauge(
+        "repro_executor_state_bytes",
+        "Bytes of a stateful task's state held on its slots' devices.",
+        unit="bytes", labels={"task": task})
 
 
 @functools.partial(jax.jit, static_argnames="bounds")
@@ -110,6 +140,96 @@ def _interleave(outs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
     one program."""
     return {k: jnp.concatenate([o[k] for o in outs], axis=0)
             for k in outs[0]}
+
+
+@functools.partial(jax.jit, static_argnames=("key", "threads"))
+def _keyroute(arrays: Dict[str, jax.Array], key: str,
+              threads: Tuple[int, ...]):
+    """Each tuple's owner, as the index of its slot in ``threads`` (the
+    task's thread count per slot, in slot order), and one part per slot:
+    the whole frame with every row the slot does not own masked invalid."""
+    n = arrays[key].shape[0]
+    thread = hash32(arrays[key]) % jnp.uint32(sum(threads))
+    owner = jnp.searchsorted(jnp.cumsum(jnp.asarray(threads, jnp.uint32)),
+                             thread, side="right").astype(jnp.int32)
+    valid = arrays.get("valid", jnp.ones((n,), bool))
+    return owner, [{**arrays, "valid": valid & (owner == k)}
+                   for k in range(len(threads))]
+
+
+def _rows_where(mask, v):
+    return jnp.reshape(mask, mask.shape + (1,) * (v.ndim - 1))
+
+
+@functools.partial(jax.jit, static_argnames="parts")
+def _keymerge(outs: List[Dict[str, jax.Array]], owner: jax.Array,
+              parts: Tuple[int, ...]) -> Dict[str, jax.Array]:
+    """Each row from its owner's output; ``parts`` names the slot index of
+    each output, and a row whose owner's part was lost is zero and not
+    valid."""
+    merged = {k: jnp.zeros_like(v) for k, v in outs[0].items()}
+    for out, k in zip(outs, parts):
+        mine = owner == k
+        merged = {f: jnp.where(_rows_where(mine, v), out[f], v)
+                  for f, v in merged.items()}
+    return merged
+
+
+@functools.partial(jax.jit, static_argnames="branches")
+def _union(ins: List[Dict[str, jax.Array]],
+           branches: Tuple[int, ...]) -> Dict[str, jax.Array]:
+    """The rows of every input one after another, each field of any input
+    present (zeros in the rows of an input without it), ``branch`` the
+    input's in-edge index and ``valid`` its mask (all valid without one)."""
+    sizes = [next(iter(x.values())).shape[0] for x in ins]
+    like = {}
+    for x in ins:
+        for f, v in x.items():
+            like.setdefault(f, v)
+    out = {f: jnp.concatenate([
+        x[f] if f in x else jnp.zeros((n,) + v.shape[1:], v.dtype)
+        for x, n in zip(ins, sizes)]) for f, v in like.items()}
+    out["valid"] = jnp.concatenate([x.get("valid", jnp.ones((n,), bool))
+                                    for x, n in zip(ins, sizes)])
+    out["branch"] = jnp.concatenate([jnp.full((n,), b, jnp.int32)
+                                     for b, n in zip(branches, sizes)])
+    return out
+
+
+def _slot_groups(schedule: Schedule) -> Dict[str, Dict]:
+    """The schedule's slot groups per task.  A stateful kind without a key
+    (global grouping) holds one state table and takes every tuple on its
+    first thread, so a schedule that spreads it over two or more slots is
+    refused: the planner would count the other slots' threads as capacity
+    that never works."""
+    groups = slot_groups(schedule.mapping, schedule.allocation)
+    for task, g in groups.items():
+        keyed = KEYED.get(schedule.allocation.tasks[task].kind)
+        if keyed is not None and keyed.key is None and len(g) > 1:
+            raise ValueError(
+                f"task {task!r} is globally grouped but mapped to {len(g)} "
+                "slots; its threads must share one slot")
+    return groups
+
+
+def _kind_fn(kind: str):
+    return KEYED[kind].fn if kind in KEYED else OPERATORS[kind]
+
+
+def _slot_order(g) -> List:
+    return sorted(g, key=lambda s: (s.vm, s.slot))
+
+
+def _row_owners(g, keyed) -> np.ndarray:
+    """Index (in slot order) of the slot that owns each state row of a
+    keyed task with thread counts ``g``: the routing of ``_keyroute``
+    applied to the row's key."""
+    if keyed.key is None:
+        return np.zeros(keyed.rows, np.int64)
+    threads = np.array([g[s] for s in _slot_order(g)], np.uint32)
+    thread = hash32(np.arange(keyed.rows, dtype=np.uint32), xp=np) \
+        % np.uint32(threads.sum())
+    return np.searchsorted(np.cumsum(threads), thread, side="right")
 
 
 @dataclasses.dataclass
@@ -155,6 +275,7 @@ class RebindInfo:
     transplanted: Dict = dataclasses.field(default_factory=dict)  # old->new
     reused_ops: int = 0
     fresh_ops: int = 0
+    state_moved_bytes: int = 0   # keyed state carried to new owners
 
 
 class _FrameTimeout(RuntimeError):
@@ -187,7 +308,7 @@ class StreamExecutor:
         self.robust = robustness if robustness is not None else RobustnessPolicy()
         self.clock = clock if clock is not None else WallClock()
         self.dag = schedule.dag
-        self.groups = slot_groups(schedule.mapping, schedule.allocation)
+        self.groups = _slot_groups(schedule)
         self._devices = list(devices) if devices is not None \
             else jax.devices()
         if not self._devices:
@@ -201,10 +322,13 @@ class StreamExecutor:
         # input was placed on (see _invoke_part)
         self._ops = {}
         for task, g in self.groups.items():
-            kind = schedule.allocation.tasks[task].kind
-            fn = OPERATORS[kind]
+            fn = _kind_fn(schedule.allocation.tasks[task].kind)
             for slot in g:
                 self._ops[(task, slot)] = jax.jit(fn)  # lint: ok JAX101 - one-time __init__ cache, each (task, slot) jitted once
+        #: (task, slot) -> state table of a stateful task, on the slot's
+        #: device for the lifetime of the group
+        self._state: Dict[Tuple[str, object], Dict[str, jax.Array]] = {}
+        self._carry_state({}, {}, {})
         self._frame_count = defaultdict(int)
         # robustness state (survives rebinds for surviving slots)
         self._consecutive_failures: Dict = defaultdict(int)
@@ -236,16 +360,18 @@ class StreamExecutor:
         of redirected slots (``transplants``: failed slot -> replacement
         slot — the ``VmFail`` repair path, which inherits the old slot's
         device pin so the compiled executable carries over verbatim), and
-        jit fresh only for genuinely new groups.
+        jit fresh only for genuinely new groups.  A schedule the executor
+        refuses (:func:`_slot_groups`) raises before anything changes.
         """
+        groups = _slot_groups(new_schedule)
         old_ops = self._ops
+        old_groups = self.groups
         old_devices = dict(self.slot_device)
         transplants = dict(transplants or {})
         reverse = {new: old for old, new in transplants.items()}
         self.schedule = new_schedule
         self.dag = new_schedule.dag
-        self.groups = slot_groups(new_schedule.mapping,
-                                  new_schedule.allocation)
+        self.groups = groups
         # device pins: keep surviving slots, inherit across transplants
         # (the replacement slot takes the failed slot's device so the
         # compiled executable can carry over verbatim), round-robin fresh
@@ -266,8 +392,7 @@ class StreamExecutor:
         kept: Set = set()
         restarted: Set = set()
         for task, g in self.groups.items():
-            kind = new_schedule.allocation.tasks[task].kind
-            fn = OPERATORS[kind]
+            fn = _kind_fn(new_schedule.allocation.tasks[task].kind)
             for slot in g:
                 key = (task, slot)
                 if key in old_ops:
@@ -289,6 +414,8 @@ class StreamExecutor:
                 self._ops[key] = jax.jit(fn)  # lint: ok JAX101 - rebind jits each new (task, slot) once
                 info.fresh_ops += 1
                 restarted.add(slot)
+        info.state_moved_bytes = self._carry_state(old_groups, self._state,
+                                                   reverse)
         info.kept_slots = sorted(kept, key=lambda s: (s.vm, s.slot))
         info.restarted_slots = sorted(restarted,
                                       key=lambda s: (s.vm, s.slot))
@@ -299,6 +426,51 @@ class StreamExecutor:
             s: n for s, n in self._consecutive_failures.items()
             if s in live_slots})
         return info
+
+    def _carry_state(self, old_groups, old_state, reverse) -> int:
+        """Give every (task, slot) group of a stateful task its table: a
+        surviving slot keeps its own, a transplanted slot inherits its old
+        slot's, a new slot starts fresh; then each state row whose owner
+        changed is copied from its old owner's table.  Returns the bytes
+        moved."""
+        moved = 0
+        self._state = {}
+        for task, g in self.groups.items():
+            keyed = KEYED.get(self.schedule.allocation.tasks[task].kind)
+            if keyed is None or not g:
+                continue
+            slots = _slot_order(g)
+            new_owner = _row_owners(g, keyed)
+            og = old_groups.get(task) or {}
+            old_slots = _slot_order(og)
+            old_owner = _row_owners(og, keyed) if og and all(
+                (task, o) in old_state for o in old_slots) else None
+            for i, slot in enumerate(slots):
+                dev = self.slot_device[slot]
+                src = slot if (task, slot) in old_state else reverse.get(slot)
+                table = old_state.get((task, src))
+                if table is None:
+                    table = jax.device_put(keyed.init(), dev)
+                if old_owner is None:
+                    self._state[(task, slot)] = table
+                    continue
+                for j, old in enumerate(old_slots):
+                    rows = np.flatnonzero((new_owner == i) & (old_owner == j))
+                    if old == src or rows.size == 0:
+                        continue
+                    with _obs_span("executor.state_move"):
+                        got = jax.device_put(
+                            {k: v[rows] for k, v in
+                             old_state[(task, old)].items()}, dev)
+                        table = {k: v.at[rows].set(got[k])
+                                 for k, v in table.items()}
+                    moved += sum(v.nbytes for v in got.values())
+                self._state[(task, slot)] = table
+            _state_gauge(task).set(sum(
+                v.nbytes for s in slots
+                for v in self._state[(task, s)].values()))
+        _STATE_MOVED.inc(moved)
+        return moved
 
     def take_escalations(self) -> List[int]:
         """VM ids the circuit breaker tripped since the last call — the
@@ -351,13 +523,19 @@ class StreamExecutor:
         return n / max(cap, 1e-9)
 
     def _invoke_part(self, task: str, slot, part, frame_seq: int,
-                     deadline_at: float) -> Optional[Dict[str, jax.Array]]:
+                     deadline_at: float, n: Optional[float] = None
+                     ) -> Optional[Dict[str, jax.Array]]:
         """One routed part through retry/backoff, fault injection, and the
         circuit breaker.  Returns the operator output, or None when the
         part was lost (exhausted retries / tripped VM).  Only the modelled
         operator failure (:class:`InjectedOperatorError`) is retried; a
-        JAX or XLA error (compile, out of memory, lost device) propagates."""
-        n = next(iter(part.values())).shape[0]
+        JAX or XLA error (compile, out of memory, lost device) propagates.
+        ``n`` is the part's tuple count where it is not the part's length
+        (a keyed part is the whole frame, masked).  A stateful group's
+        table is replaced only once its call has completed."""
+        if n is None:
+            n = next(iter(part.values())).shape[0]
+        state = self._state.get((task, slot))
         fail_attempts = 0
         slow = 1.0
         if self.faults is not None:
@@ -384,10 +562,12 @@ class StreamExecutor:
                         else FaultKind.VM_CRASH, task)
                 t0 = time.perf_counter()
                 with _obs_span("executor.launch"):
-                    out = op(part)
+                    out = op(part) if state is None else op(state, part)
                 # wait for the device: busy is its time, not the enqueue's
                 with _obs_span("executor.wait"):
                     out = jax.block_until_ready(out)
+                if state is not None:
+                    self._state[(task, slot)], out = out
                 busy = time.perf_counter() - t0
                 if self.clock.virtual:
                     busy = self._virtual_cost(task, slot, n)
@@ -427,6 +607,59 @@ class StreamExecutor:
         if not g:
             return arrays
         kind = self.schedule.allocation.tasks[task].kind
+        keyed = KEYED.get(kind)
+        owner = None
+        if keyed is not None:
+            routed, pieces, owner = self._route_keyed(task, keyed, arrays)
+        else:
+            routed, pieces = self._route(task, arrays)
+        parts = {}
+        lost = False
+        for (slot, count), part in zip(routed, pieces):
+            if slot.vm in self.tripped_vms:
+                # breaker open: skip the dead VM's share entirely
+                self._run_counters["tuples_lost"] = \
+                    self._run_counters.get("tuples_lost", 0) + count
+                _TUPLES_LOST.inc(count)
+                lost = True
+                continue
+            out = self._invoke_part(task, slot, part, frame_seq, deadline_at,
+                                    n=count)
+            if out is None:
+                lost = True
+            else:
+                parts[slot] = out
+                self._frame_count[str(self.slot_device[slot])] += 1
+        if lost:
+            self._run_counters["frame_lost_tuples"] = 1
+        if kind in SERVICE_LATENCY:
+            # external service wait, parallelized over the task's threads
+            q_total = sum(g.values())
+            with _obs_span("executor.service"):
+                self.clock.sleep(SERVICE_LATENCY[kind] / max(1, q_total))
+        outs = list(parts.values())
+        if not outs:
+            return arrays if not lost else {}
+        if owner is not None:
+            # every row back from its owner's output, on the first slot
+            with _obs_span("executor.gather"):
+                slots = [s for s, _ in routed]
+                home = self.slot_device[slots[0]]
+                outs, owner = jax.device_put((outs, owner), home)
+                return _keymerge(outs, owner, parts=tuple(
+                    slots.index(s) for s in parts))
+        if len(outs) == 1:
+            return outs[0]
+        # interleave across slots: gather to one device (the real tuple
+        # movement between slots that Storm's network transfer performs)
+        with _obs_span("executor.gather"):
+            home = self.slot_device[next(iter(parts))]
+            _ROUTE_LAUNCHES["interleave"].inc()
+            return _interleave(jax.device_put(outs, home))
+
+    def _route(self, task: str, arrays: Dict[str, jax.Array]):
+        """``[(slot, tuples)]`` and the parts of a stateless task: the frame
+        cut into contiguous non-empty parts in proportion to the weights."""
         n = next(iter(arrays.values())).shape[0]
         with _obs_span("executor.route"):
             weights = self._weights(task)
@@ -449,40 +682,39 @@ class StreamExecutor:
                 # one part is the whole frame; a host frame slices for free
                 pieces = [{k: v[lo:hi] for k, v in arrays.items()}
                           for lo, hi in bounds]
-        parts = {}
-        lost = False
-        for (slot, lo, hi), part in zip(routed, pieces):
-            if slot.vm in self.tripped_vms:
-                # breaker open: skip the dead VM's share entirely
-                self._run_counters["tuples_lost"] = \
-                    self._run_counters.get("tuples_lost", 0) + (hi - lo)
-                _TUPLES_LOST.inc(hi - lo)
-                lost = True
-                continue
-            out = self._invoke_part(task, slot, part, frame_seq, deadline_at)
-            if out is None:
-                lost = True
-            else:
-                parts[slot] = out
-                self._frame_count[str(self.slot_device[slot])] += 1
-        if lost:
-            self._run_counters["frame_lost_tuples"] = 1
-        if kind in SERVICE_LATENCY:
-            # external service wait, parallelized over the task's threads
-            q_total = sum(g.values())
-            with _obs_span("executor.service"):
-                self.clock.sleep(SERVICE_LATENCY[kind] / max(1, q_total))
-        outs = list(parts.values())
-        if not outs:
-            return arrays if not lost else {}
-        if len(outs) == 1:
-            return outs[0]
-        # interleave across slots: gather to one device (the real tuple
-        # movement between slots that Storm's network transfer performs)
-        with _obs_span("executor.gather"):
-            home = self.slot_device[next(iter(parts))]
-            _ROUTE_LAUNCHES["interleave"].inc()
-            return _interleave(jax.device_put(outs, home))
+        return [(slot, hi - lo) for slot, lo, hi in routed], pieces
+
+    def _route_keyed(self, task: str, keyed, arrays: Dict[str, jax.Array]):
+        """``[(slot, tuples)]``, the parts and each tuple's owner (None for
+        one part) of a stateful task.  With a key and two or more slots
+        every slot gets the whole frame with the rows it does not own
+        masked, and its count is its expected share; otherwise the frame
+        goes whole to the task's first slot."""
+        g = self.groups[task]
+        slots = _slot_order(g)
+        n = next(iter(arrays.values())).shape[0]
+        if keyed.key is None or len(slots) == 1:
+            if "valid" not in arrays:
+                arrays = {**arrays, "valid": np.ones(n, bool)}
+            return [(slots[0], n)], [arrays], None
+        t0 = time.perf_counter()
+        with _obs_span("executor.keyroute"):
+            threads = tuple(g[s] for s in slots)
+            owner, pieces = _keyroute(arrays, key=keyed.key, threads=threads)
+        _KEYROUTE_SECONDS.inc(time.perf_counter() - t0)
+        q = sum(threads)
+        return [(s, n * g[s] / q) for s in slots], pieces, owner
+
+    def _merge(self, upstream: List[Tuple[int, Dict[str, jax.Array]]]
+               ) -> Dict[str, jax.Array]:
+        """The union of the in-edges' outputs ``(in-edge index, arrays)``,
+        on the device of the first."""
+        with _obs_span("executor.merge"):
+            ins = [x for _, x in upstream]
+            first = next(iter(ins[0].values()))
+            if isinstance(first, jax.Array):
+                ins = jax.device_put(ins, next(iter(first.devices())))
+            return _union(ins, branches=tuple(i for i, _ in upstream))
 
     def process_frame(self, frame: MicroBatch, interval: float
                       ) -> Tuple[str, Optional[float]]:
@@ -522,11 +754,15 @@ class StreamExecutor:
                 if not ins:
                     arrays = frame.arrays
                 else:
-                    upstream = [outputs[e.src] for e in ins
+                    upstream = [(i, outputs[e.src]) for i, e in enumerate(ins)
                                 if e.src in outputs and outputs[e.src]]
                     if not upstream:
                         continue
-                    arrays = upstream[0]  # interleave: take one copy (sel 1:1)
+                    if t.kind in MERGES:
+                        arrays = self._merge(upstream)
+                    else:
+                        # interleave: take one copy (sel 1:1)
+                        arrays = upstream[0][1]
                 outputs[t.name] = self._run_task(t.name, arrays, frame.seq,
                                                  deadline_at)
         except _FrameTimeout:

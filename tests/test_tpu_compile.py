@@ -2,7 +2,8 @@
 
 Nothing runs: the TPU compiler, which is installed even where no chip is
 attached, compiles the co-simulation scan kernel, one vmapped search
-bucket and the stream operators at the sizes ``chip_smoke.py`` drives, and
+bucket and the stream operators at the sizes ``chip_smoke.py`` drives (the
+stateful STATS kinds with their whole state tables), and
 raises what the chip's compiler would raise (unsupported ops, programs that
 do not fit).  The topology is described inside a module fixture, never at
 import, so every test worker collects the same tests and only the worker
@@ -23,7 +24,7 @@ from repro.core.search import (bucket_row_slices, generate_candidates,
                                shape_buckets)
 from repro.core.simulator import SweepBatch, _sweep_steps, get_scan_kernel
 from repro.jaxenv import x64
-from repro.runtime.operators import OPERATORS
+from repro.runtime.operators import KEYED, OPERATORS
 
 #: the executor's frame: LiveFleet's 16-tuple batch of 256-byte payloads
 FRAME, PAYLOAD = 16, 256
@@ -142,3 +143,46 @@ def test_operator_compiles_for_v5e(kind, one_chip):
              "value": _sds((FRAME,), np.float32, one_chip)}
     compiled = jax.jit(OPERATORS[kind]).lower(frame).compile()
     assert _on_tpu(compiled)
+
+
+def _stats_frame(kind, one_chip):
+    """What a STATS kind reads at the executor's frame: 16 parsed SYS
+    records, or the accumulator's union of its three in-edges (48 rows)."""
+    from repro.runtime.operators import SYS_FIELDS
+    n = 3 * FRAME if kind == "accumulate" else FRAME
+    frame = {"sensor": _sds((n,), np.int32, one_chip),
+             "ts": _sds((n,), np.int32, one_chip),
+             "obs": _sds((n, SYS_FIELDS), np.float32, one_chip),
+             "valid": _sds((n,), np.bool_, one_chip)}
+    if kind == "sliding_linear_regression":
+        frame["kalman"] = _sds((n, SYS_FIELDS), np.float32, one_chip)
+    if kind == "accumulate":
+        frame.update(avg=_sds((n, SYS_FIELDS), np.float32, one_chip),
+                     slr=_sds((n, SYS_FIELDS), np.float32, one_chip),
+                     distinct=_sds((n,), np.float32, one_chip),
+                     branch=_sds((n,), np.int32, one_chip))
+    return frame
+
+
+@pytest.mark.parametrize("kind", sorted(KEYED))
+def test_stateful_operator_compiles_for_v5e(kind, one_chip):
+    """Every stateful kind with its whole state table (1,000 sensors) at
+    the executor's frame shape."""
+    state = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                         jax.eval_shape(KEYED[kind].init))
+    compiled = jax.jit(KEYED[kind].fn).lower(
+        state, _stats_frame(kind, one_chip)).compile()
+    assert _on_tpu(compiled)
+
+
+def test_keyed_route_compiles_for_v5e(one_chip):
+    """The keyed route and merge over three slots."""
+    from repro.runtime import executor
+    frame = _stats_frame("average", one_chip)
+    route = executor._keyroute.lower(frame, key="sensor",
+                                     threads=(2, 1, 3)).compile()
+    assert _on_tpu(route)
+    owner = _sds((FRAME,), np.int32, one_chip)
+    merge = executor._keymerge.lower([frame] * 3, owner,
+                                     parts=(0, 1, 2)).compile()
+    assert _on_tpu(merge)
