@@ -14,6 +14,9 @@ slot-pinned jitted operator, and the results interleave downstream — the
 Storm execution model of §2.  A device frame cut into two or more parts is
 cut by one compiled program, and the parts' outputs are joined by another,
 so a task's route costs one launch each way whatever its keys and parts.
+Every part of every task is launched without a host wait (JAX orders the
+work on each device, a ``device_put`` of an array not yet ready included),
+and the frame blocks once per sink reached.
 
 A stateful kind (``operators.KEYED``) keeps a state table on the device of
 each of its slots, and its tuples are routed by key (Storm's fields
@@ -53,15 +56,19 @@ performance-model tables (``truth`` — the measured "ground truth" library),
 which makes whole chaos replays deterministic and sleep-free.
 
 Measured per-(task, slot-group) service rates accumulate in the executor
-and feed :mod:`repro.core.calibrate` — the measure→recalibrate loop.
+and feed :mod:`repro.core.calibrate` — the measure→recalibrate loop.  Under
+the wall clock a part's busy time is its host time in ``executor.launch``
+plus a share of the frame's sink waits in proportion to that time; each
+part's sample is held until the frame returns.
 
 Telemetry (:mod:`repro.obs`, free while off): :meth:`process_frame` opens
 an ``executor.frame`` span with one child span per stage of the frame
-(``route``, ``keyroute``, ``merge``, ``place``, ``launch``, ``wait``,
-``service``, ``gather``, ``sink_wait``), counts frames and tuples at the
-sites that decide their fate, the compiled split and interleave launches
-(``repro_executor_route_launches_total``), the host time of keyed routing,
-and the bytes of state held and moved by rebinds.
+(``route``, ``keyroute``, ``merge``, ``place``, ``launch``, ``service``,
+``gather``, ``sink_wait``), counts frames and tuples at the sites that
+decide their fate, the compiled split and interleave launches
+(``repro_executor_route_launches_total``), the host blocks
+(``repro_executor_host_waits_total``), the host time of keyed routing, and
+the bytes of state held and moved by rebinds.
 """
 
 from __future__ import annotations
@@ -108,6 +115,10 @@ _ROUTE_LAUNCHES = {
         "Compiled programs launched to split a frame over a task's slot "
         "groups or to interleave their outputs.", labels={"stage": stage})
     for stage in ("split", "interleave")}
+_HOST_WAITS = _obs_metrics.counter(
+    "repro_executor_host_waits_total",
+    "Host blocks on device results inside process_frame: one per sink "
+    "reached per frame.")
 _KEYROUTE_SECONDS = _obs_metrics.counter(
     "repro_executor_keyroute_seconds_total",
     "Host seconds spent finding the owner thread of each tuple of a keyed "
@@ -283,7 +294,9 @@ class _FrameTimeout(RuntimeError):
 
 
 class StreamExecutor:
-    """Synchronous frame-at-a-time executor (demo-scale faithful enactment).
+    """Frame-at-a-time executor (demo-scale faithful enactment): a frame's
+    parts are dispatched without a wait, and the frame returns once its
+    sink outputs are ready.
 
     ``clock`` selects wall vs virtual time; ``truth`` is the model library
     whose tables price operator work under a virtual clock (defaults to
@@ -338,6 +351,10 @@ class StreamExecutor:
         # busy_s] — keyed by the thread count at invocation time, so
         # samples from before and after a rebind never mix thread counts
         self._measured: Dict[Tuple[str, object, int], List[float]] = {}
+        #: this frame's samples, held until it returns: (task, slot,
+        #: threads, tuples, busy_s before the sink waits' share, slowdown)
+        self._samples: List[Tuple[str, object, int, float, float,
+                                  float]] = []
         self._run_counters: Dict[str, int] = {}
         #: frames consumed across ALL runs — the fault plan's frame axis
         #: continues across measurement windows (chaos determinism)
@@ -531,8 +548,10 @@ class StreamExecutor:
         operator failure (:class:`InjectedOperatorError`) is retried; a
         JAX or XLA error (compile, out of memory, lost device) propagates.
         ``n`` is the part's tuple count where it is not the part's length
-        (a keyed part is the whole frame, masked).  A stateful group's
-        table is replaced only once its call has completed."""
+        (a keyed part is the whole frame, masked).  The operator is
+        launched without a wait, so a device error surfaces at the frame's
+        sink wait; a stateful group's table becomes the call's output at
+        once.  The part's busy sample is held for :meth:`_record_busy`."""
         if n is None:
             n = next(iter(part.values())).shape[0]
         state = self._state.get((task, slot))
@@ -560,27 +579,20 @@ class StreamExecutor:
                         FaultKind.OPERATOR_ERROR
                         if not self.faults.is_crashed(slot.vm)
                         else FaultKind.VM_CRASH, task)
-                t0 = time.perf_counter()
                 with _obs_span("executor.launch"):
+                    t0 = time.perf_counter()
                     out = op(part) if state is None else op(state, part)
-                # wait for the device: busy is its time, not the enqueue's
-                with _obs_span("executor.wait"):
-                    out = jax.block_until_ready(out)
+                    busy = time.perf_counter() - t0
                 if state is not None:
                     self._state[(task, slot)], out = out
-                busy = time.perf_counter() - t0
                 if self.clock.virtual:
                     busy = self._virtual_cost(task, slot, n)
-                busy *= slow
-                if slow > 1.0 and not self.clock.virtual:
+                elif slow > 1.0:
                     # realize the slowdown in wall time too
-                    self.clock.sleep(busy - busy / slow)
+                    self.clock.sleep(busy * (slow - 1.0))
                 self._consecutive_failures[slot] = 0
-                q = self.groups[task][slot]
-                acc = self._measured.setdefault((task, slot, int(q)),
-                                                [0.0, 0.0])
-                acc[0] += n
-                acc[1] += busy
+                self._samples.append((task, slot, int(self.groups[task][slot]),
+                                      n, busy, slow))
                 return out
             except InjectedOperatorError:
                 if attempt >= self.robust.max_retries:
@@ -599,6 +611,18 @@ class StreamExecutor:
             self.tripped_vms.add(slot.vm)
             self._pending_escalations.append(slot.vm)
         return None
+
+    def _record_busy(self, wait: float) -> None:
+        """Add the frame's held samples to the measured busy time, sharing
+        the sink waits ``wait`` (seconds on the executor's clock, so none
+        under a virtual one) over the parts in proportion to their busy."""
+        samples, self._samples = self._samples, []
+        launched = sum(s[4] for s in samples)
+        per_launch = wait / launched if launched > 0 else 0.0
+        for task, slot, q, n, busy, slow in samples:
+            acc = self._measured.setdefault((task, slot, q), [0.0, 0.0])
+            acc[0] += n
+            acc[1] += (busy + busy * per_launch) * slow
 
     def _run_task(self, task: str, arrays: Dict[str, jax.Array],
                   frame_seq: int = -1,
@@ -746,44 +770,59 @@ class StreamExecutor:
         deadline_at = (now + self.robust.frame_deadline_intervals * interval
                        if interval > 0 else float("inf"))
         self._run_counters.pop("frame_lost_tuples", None)
-        topo = self.dag.topo_order()
-        outputs: Dict[str, Dict[str, jax.Array]] = {}
+        wait = 0.0
         try:
-            for t in topo:
-                ins = self.dag.in_edges(t.name)
-                if not ins:
-                    arrays = frame.arrays
+            try:
+                outputs = self._launch_tasks(frame, deadline_at)
+            except _FrameTimeout:
+                self._run_counters["frames_timed_out"] = \
+                    self._run_counters.get("frames_timed_out", 0) + 1
+                _FRAMES_TIMED_OUT.inc()
+                return "timeout", None
+            # the frame's one block per sink: a truthful completion time,
+            # and where an error of any part's device work surfaces
+            self.last_sink_outputs = {}
+            for snk in self.dag.sinks():
+                out = outputs.get(snk.name)
+                if out:
+                    with _obs_span("executor.sink_wait"):
+                        t0 = self.clock.now()
+                        jax.block_until_ready(out)
+                        wait += self.clock.now() - t0
+                    _HOST_WAITS.inc()
+                    self.last_sink_outputs[snk.name] = out
+            if self._run_counters.pop("frame_lost_tuples", None):
+                self._run_counters["frames_failed"] = \
+                    self._run_counters.get("frames_failed", 0) + 1
+                _FRAMES_FAILED.inc()
+                return "failed", None
+            return "ok", self.clock.now() - frame.created
+        finally:
+            # the waits on the executor's clock: none under a virtual one
+            self._record_busy(wait)
+
+    def _launch_tasks(self, frame: MicroBatch, deadline_at: float
+                      ) -> Dict[str, Dict[str, jax.Array]]:
+        """Every task's output of the frame, launched in topological order
+        without a wait."""
+        outputs: Dict[str, Dict[str, jax.Array]] = {}
+        for t in self.dag.topo_order():
+            ins = self.dag.in_edges(t.name)
+            if not ins:
+                arrays = frame.arrays
+            else:
+                upstream = [(i, outputs[e.src]) for i, e in enumerate(ins)
+                            if e.src in outputs and outputs[e.src]]
+                if not upstream:
+                    continue
+                if t.kind in MERGES:
+                    arrays = self._merge(upstream)
                 else:
-                    upstream = [(i, outputs[e.src]) for i, e in enumerate(ins)
-                                if e.src in outputs and outputs[e.src]]
-                    if not upstream:
-                        continue
-                    if t.kind in MERGES:
-                        arrays = self._merge(upstream)
-                    else:
-                        # interleave: take one copy (sel 1:1)
-                        arrays = upstream[0][1]
-                outputs[t.name] = self._run_task(t.name, arrays, frame.seq,
-                                                 deadline_at)
-        except _FrameTimeout:
-            self._run_counters["frames_timed_out"] = \
-                self._run_counters.get("frames_timed_out", 0) + 1
-            _FRAMES_TIMED_OUT.inc()
-            return "timeout", None
-        # block on one sink output to get a truthful completion time
-        self.last_sink_outputs = {}
-        for snk in self.dag.sinks():
-            out = outputs.get(snk.name)
-            if out:
-                with _obs_span("executor.sink_wait"):
-                    jax.block_until_ready(next(iter(out.values())))
-                self.last_sink_outputs[snk.name] = out
-        if self._run_counters.pop("frame_lost_tuples", None):
-            self._run_counters["frames_failed"] = \
-                self._run_counters.get("frames_failed", 0) + 1
-            _FRAMES_FAILED.inc()
-            return "failed", None
-        return "ok", self.clock.now() - frame.created
+                    # interleave: take one copy (sel 1:1)
+                    arrays = upstream[0][1]
+            outputs[t.name] = self._run_task(t.name, arrays, frame.seq,
+                                             deadline_at)
+        return outputs
 
     def run(self, omega: float, *, duration: float = 2.0,
             batch: int = 32, warmup_frames: int = 2,
