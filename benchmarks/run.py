@@ -15,9 +15,8 @@ from repro.jaxenv import init_compile_cache
 
 from . import (bench_app_dags, bench_chaos, bench_fleet, bench_latency,
                bench_mapper_search, bench_micro_dags, bench_obs,
-               bench_online, bench_optimized, bench_perfmodels,
-               bench_predictability, bench_prove, bench_roofline,
-               bench_serving, bench_sweep)
+               bench_online, bench_perfmodels, bench_predictability,
+               bench_prove, bench_sweep)
 from .common import timed
 
 BENCHES = [
@@ -34,9 +33,6 @@ BENCHES = [
     ("obs_telemetry", bench_obs.run),
     ("chaos_enactment", bench_chaos.run),
     ("rate_prover", bench_prove.run),
-    ("serving_planner", bench_serving.run),
-    ("roofline_table", bench_roofline.run),
-    ("perf_optimized", bench_optimized.run),
 ]
 
 

@@ -104,12 +104,9 @@ def vm_classes_from_sizes(sizes: Sequence[int], *, speed: float = 1.0,
 
 
 #: Named class families used by the repo's planners: the paper's Azure
-#: D-series (D3=4/D2=2/D1=1 slots), the serving planner's TPU hosts, and
-#: the data-pipeline hosts (8-core machines down to singles).
+#: D-series (D3=4/D2=2/D1=1 slots).
 VM_CLASS_FAMILIES: Dict[str, Tuple[VmClass, ...]] = {
     "azure-d": vm_classes_from_sizes((4, 2, 1)),
-    "tpu-host": vm_classes_from_sizes((4, 2, 1), prefix="host"),
-    "pipeline-host": vm_classes_from_sizes((8, 4, 2, 1), prefix="host"),
 }
 
 

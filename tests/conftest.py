@@ -1,12 +1,6 @@
-"""Shared fixtures.  NOTE: no XLA_FLAGS here on purpose — smoke tests and
-benches must see the real single CPU device; only launch/dryrun (a fresh
-process) forces 512 host devices.
+"""Shared fixtures.  NOTE: no XLA_FLAGS here on purpose — the tests and
+benches must see the real single CPU device."""
 
-The ``slow`` marker (registered here and deselected by default via the
-``addopts`` in pyproject.toml) covers the subprocess/compile-heavy tests;
-run them with ``pytest -m slow`` (or everything with ``-m ""``)."""
-
-import jax
 import pytest
 
 from repro.core import paper_library, set_default_validate
@@ -24,18 +18,6 @@ def _validate_all_plans():
     set_default_validate(prev)
 
 
-def pytest_configure(config):
-    config.addinivalue_line(
-        "markers",
-        "slow: subprocess- or compile-heavy test, deselected by default "
-        "(run with -m slow)")
-
-
 @pytest.fixture(scope="session")
 def lib():
     return paper_library()
-
-
-@pytest.fixture(scope="session")
-def key():
-    return jax.random.PRNGKey(0)

@@ -1,7 +1,7 @@
 """Clean hand-over-hand pattern: the analyzer must stay silent here.
 
 The future is swapped out *under* the lock and blocked on with the lock
-released — the shape ``repro.train.checkpoint.Checkpointer.wait`` uses.
+released — the shape an asynchronous writer's ``wait`` takes.
 Zero findings expected (the false-positive guard for RACE211/RACE212).
 """
 

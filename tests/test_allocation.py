@@ -1,4 +1,7 @@
-"""LSA (Alg. 2) and MBA (Alg. 3) allocation."""
+"""LSA (Alg. 2) and MBA (Alg. 3) allocation, and both against a plain
+reference written from the algorithms."""
+
+import math
 
 try:
     import hypothesis
@@ -124,3 +127,71 @@ def test_allocation_monotone_in_rate(omega):
     a2 = allocate_mba(dag, omega * 2, lib)
     assert a2.slots >= a1.slots
     assert a2.total_threads >= a1.total_threads
+
+
+# -- a plain reference, straight from Alg. 2 and Alg. 3 -----------------------
+
+def _covering_threads(model, rate):
+    """The smallest thread count ``q >= 1`` whose ``I(q)`` covers ``rate``,
+    by a plain walk up the integers (None past the last measured count)."""
+    for q in range(1, model.points[-1].tau + 1):
+        if model.I(q) >= rate:
+            return q
+    return None
+
+
+def _reference_task(model, algorithm, rate):
+    """One task's (threads, cpu, mem) as Alg. 2 (LSA) or Alg. 3 (MBA) has
+    it, from the profile's ``I``/``C``/``M`` alone."""
+    if model.static:                # §8.3: source and sink, one thread
+        return 1, model.C(1), model.M(1)
+    if algorithm == "lsa":
+        # one thread per omega_bar of rate; the residual thread's
+        # resources scaled from one thread's
+        omega_bar = model.I(1)
+        full = math.floor(rate / omega_bar)
+        resid = rate - full * omega_bar
+        threads, cpu, mem = full, full * model.C(1), full * model.M(1)
+        if resid > 0:
+            threads += 1
+            cpu += model.C(1) * resid / omega_bar
+            mem += model.M(1) * resid / omega_bar
+        return threads, cpu, mem
+    # MBA: full bundles of tau_hat threads at omega_hat, a whole slot each;
+    # the residual on the fewest threads that cover it
+    omega_hat = max(p.rate for p in model.points)
+    tau_hat = _covering_threads(model, omega_hat)
+    bundles = math.floor(rate / omega_hat)
+    resid = rate - bundles * omega_hat
+    threads, cpu, mem = bundles * tau_hat, float(bundles), float(bundles)
+    if resid > 0:
+        q = _covering_threads(model, resid)
+        assert q is not None
+        threads += q
+        if q == 1:
+            cpu += model.C(1) * resid / model.I(1)
+            mem += model.M(1) * resid / model.I(1)
+        else:
+            cpu += model.C(q)
+            mem += model.M(q)
+    return threads, cpu, mem
+
+
+@pytest.mark.parametrize("omega", [50, 100, 200])
+@pytest.mark.parametrize("algorithm", ["lsa", "mba"])
+@pytest.mark.parametrize("dag_name", list(ALL_DAGS))
+def test_allocation_matches_the_plain_reference(lib, dag_name, algorithm,
+                                                omega):
+    """Every task's thread count, CPU and memory equal Alg. 2 / Alg. 3
+    written out by hand, at the Fig. 7 rates."""
+    dag = ALL_DAGS[dag_name]()
+    allocate = {"lsa": allocate_lsa, "mba": allocate_mba}[algorithm]
+    alloc = allocate(dag, omega, lib)
+    rates = dag.get_rates(omega)
+    assert set(alloc.tasks) == set(rates)
+    for name, ta in alloc.tasks.items():
+        threads, cpu, mem = _reference_task(lib[ta.kind], algorithm,
+                                            rates[name])
+        assert ta.threads == threads, name
+        assert ta.cpu == pytest.approx(cpu, rel=1e-12, abs=1e-12), name
+        assert ta.mem == pytest.approx(mem, rel=1e-12, abs=1e-12), name

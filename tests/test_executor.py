@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import linear_dag, plan, traffic_dag
+from repro.core import ALL_DAGS, linear_dag, plan, traffic_dag
 from repro.core.perfmodel import PerfModel
 from repro.runtime import StreamExecutor, VirtualClock
 from repro.runtime import executor as executor_mod
@@ -110,6 +110,13 @@ def test_interleave_gives_the_eager_concatenation(n, bounds):
     _assert_same({k: got[k] for k in arrays}, arrays)
 
 
+#: Every DAG of ``ALL_DAGS`` at 100 tuples/s, the linear one at 120.
+RATES = {name: 120 if name == "linear" else 100 for name in ALL_DAGS}
+EVERY_DAG = pytest.mark.parametrize(
+    "dag, rate", [(ALL_DAGS[name], rate) for name, rate in RATES.items()],
+    ids=[f"{name}-{rate}" for name, rate in RATES.items()])
+
+
 def _executor(lib, dag=traffic_dag, rate=100):
     schedule = plan(dag(), rate, lib, allocator="mba", mapper="sam",
                     vm_sizes="azure-d")
@@ -136,8 +143,7 @@ def _outputs(ex, frames, on_device):
 
 @pytest.mark.parametrize("n", [16, 8], ids=["frame", "half-frame"])
 @pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
-@pytest.mark.parametrize("dag, rate", [(traffic_dag, 100), (linear_dag, 120)],
-                         ids=["traffic-100", "linear-120"])
+@EVERY_DAG
 def test_task_outputs_match_the_eager_route(lib, monkeypatch, dag, rate, n,
                                             on_device):
     frames = [_frame(n, seed, keys=("payload", "value"))
@@ -204,8 +210,7 @@ def _sinks(ex, frames, on_device):
 
 
 @pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
-@pytest.mark.parametrize("dag, rate", [(traffic_dag, 100), (linear_dag, 120)],
-                         ids=["traffic-100", "linear-120"])
+@EVERY_DAG
 def test_sinks_match_a_wait_after_every_part(lib, monkeypatch, dag, rate,
                                             on_device):
     frames = [_frame(16, seed, keys=("payload", "value"))

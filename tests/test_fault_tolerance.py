@@ -1,16 +1,7 @@
-"""Fault tolerance: failure replanning + elastic checkpoint re-mesh."""
-
-import os
-import subprocess
-import sys
-import textwrap
-
-import pytest
+"""Fault tolerance: replanning a schedule off failed VMs."""
 
 from repro.core import (DataflowSimulator, diamond_dag, paper_library, plan,
                         replan_on_failure)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_replan_survives_vm_failure():
@@ -39,62 +30,3 @@ def test_replan_multiple_failures():
     s2 = replan_on_failure(s, lib, failed)
     for slot in s2.mapping.assignment.values():
         assert slot.vm not in failed
-
-
-@pytest.mark.slow
-def test_elastic_checkpoint_remesh_subprocess():
-    """Save a TRAIN state sharded on a 4-device mesh, restore onto a
-    2-device mesh (shrunk cluster) and verify values — the lose-a-pod
-    recovery path."""
-    script = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        import sys; sys.path.insert(0, %r)
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import AxisType, make_mesh
-        from repro.configs import get_config
-        from repro.models import get_model
-        from repro.models.common import Env
-        from repro.distributed.sharding import tree_param_specs
-        from repro.train import AdamWConfig, Checkpointer, init_train_state
-
-        cfg = get_config("minicpm-2b").reduced()
-        api = get_model(cfg)
-        state = init_train_state(api, jax.random.PRNGKey(0), AdamWConfig())
-
-        mesh4 = make_mesh((2, 2), ("data", "model"),
-                          axis_types=(AxisType.Auto,)*2)
-        env4 = Env(mesh=mesh4, batch_axes=("data",), tp_axis="model")
-        specs = tree_param_specs(env4, state)
-        sharded = jax.tree.map(
-            lambda x, sp: jax.device_put(x, NamedSharding(mesh4, sp)),
-            state, specs, is_leaf=lambda x: hasattr(x, "shape"))
-
-        ckpt = Checkpointer("/tmp/elastic_ckpt_test", async_save=False)
-        ckpt.save(7, sharded)
-
-        # "lose half the cluster": restore onto a 2-device mesh
-        mesh2 = make_mesh((1, 2), ("data", "model"),
-                          axis_types=(AxisType.Auto,)*2)
-        env2 = Env(mesh=mesh2, batch_axes=("data",), tp_axis="model")
-        specs2 = tree_param_specs(env2, state)
-        flatmap = {}
-        def record(path, sp):
-            key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                           for p in path)
-            flatmap[key] = sp
-        jax.tree_util.tree_map_with_path(
-            record, specs2, is_leaf=lambda x: isinstance(x, P))
-        restored, step, _ = ckpt.restore(
-            state, sharding_fn=lambda key, leaf: NamedSharding(
-                mesh2, flatmap.get(key, P())))
-        assert step == 7
-        for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(restored)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        print("ELASTIC_OK")
-    """ % os.path.join(REPO, "src"))
-    proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    assert "ELASTIC_OK" in proc.stdout

@@ -310,29 +310,6 @@ def test_predict_resources_sweep_matches_scalar(lib, policy):
                     ref.vm_mem[vm], rel=1e-12, abs=1e-12)
 
 
-def test_plan_serving_fleet_objectives():
-    """The serving wrapper: per-workload model libraries + DAGs through
-    every fleet objective on one host budget."""
-    from repro.configs import get_config
-    from repro.serve import ServingWorkload, plan_serving_fleet
-
-    cfg = get_config("qwen2.5-32b")
-    wls = [ServingWorkload("chat", cfg, prompt_len=2048, gen_len=256,
-                           weight=2.0, priority=1),
-           ServingWorkload("code", cfg, prompt_len=4096, gen_len=512)]
-    for objective in ("max_min", "weighted", "priority"):
-        fp = plan_serving_fleet(wls, budget_hosts=16, objective=objective)
-        assert set(fp.entries) == {"chat", "code"}
-        assert fp.total_estimated_slots <= 16
-        for e in fp.entries.values():
-            assert (e.schedule is not None) == (e.omega > 0)
-    # the higher tier is served first when hosts are scarce
-    fp = plan_serving_fleet(wls, budget_hosts=16, objective="priority")
-    assert fp.entries["chat"].omega > 0
-    with pytest.raises(ValueError):
-        plan_serving_fleet([wls[0], wls[0]], budget_hosts=16)
-
-
 def test_fleet_resource_surfaces(lib):
     dags = {n: mk() for n, mk in MICRO_DAGS.items()}
     fp = plan_fleet(dags, lib, budget_slots=24, objective="max_min",

@@ -59,7 +59,7 @@ def test_vm_class_rejects_bad_params(kwargs):
 def test_resolve_vm_classes_forms():
     ints = resolve_vm_classes((4, 2, 1))
     assert [c.slots for c in ints] == [4, 2, 1]
-    assert resolve_vm_classes("tpu-host") == vm_class_family("tpu-host")
+    assert resolve_vm_classes("azure-d") == vm_class_family("azure-d")
     assert resolve_vm_classes(ints) == ints
     with pytest.raises(ValueError):
         resolve_vm_classes(())

@@ -3,17 +3,19 @@ reference, candidate-pool invariants, and the search-beats-single-mapper pin.
 
 The contract mirrors the scan engine's: the shape-bucketed ``jax.vmap``
 evaluation must reproduce per-candidate ``engine="numpy"`` tick loops to
-<= 1e-10 on every raw surface, for a pool spanning several shape buckets and
-both routing policies — and because every single §7 mapper is itself a
-candidate, ``mapper="search"`` can never return a worse simulated max stable
-rate than the best of DSM/RSM/SAM on the same pool.
+<= 1e-10 on every raw surface, for a pool spanning several shape buckets, on
+every DAG of ``ALL_DAGS`` and under both routing policies — and because
+every single §7 mapper is itself a candidate, ``mapper="search"`` can never
+return a worse simulated max stable rate than the best of DSM/RSM/SAM on the
+same pool.
 """
 
 import numpy as np
 import pytest
 
-from repro.core import (DataflowSimulator, RoutingPolicy, diamond_dag,
-                        linear_dag, paper_library, plan, plan_fleet)
+from repro.core import (ALL_DAGS, DataflowSimulator, RoutingPolicy,
+                        diamond_dag, linear_dag, paper_library, plan,
+                        plan_fleet)
 from repro.core.allocation import ALLOCATORS
 from repro.core.mapping import (local_moves, make_threads, mapping_signature)
 from repro.core.search import (evaluate_candidates, generate_candidates,
@@ -30,9 +32,10 @@ def lib():
 
 
 @pytest.fixture(scope="module")
-def pool(lib):
-    """One shared (dag, alloc, vms, candidates) fixture for the module."""
-    dag = diamond_dag()
+def pool(lib, request):
+    """One shared (dag, alloc, vms, candidates) fixture per DAG: the
+    diamond unless a test parametrises it by ``ALL_DAGS`` name."""
+    dag = ALL_DAGS[getattr(request, "param", "diamond")]()
     alloc = ALLOCATORS["mba"](dag, 100, lib)
     ranked = search_mapping(dag, 100, lib, n_moves=2, rate_fractions=[1.0],
                             duration=1.0, dt=0.5)
@@ -42,6 +45,7 @@ def pool(lib):
 
 # -- vmapped engine equivalence ------------------------------------------------
 
+@pytest.mark.parametrize("pool", list(ALL_DAGS), indirect=True)
 @pytest.mark.parametrize("policy", list(RoutingPolicy),
                          ids=[p.value for p in RoutingPolicy])
 def test_vmap_matches_per_candidate_numpy(lib, pool, policy):

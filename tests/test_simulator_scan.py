@@ -2,23 +2,27 @@
 
 The contract is *equivalence*: the scan kernel must reproduce the numpy tick
 loop to <= 1e-10 on every raw surface (queues, served, realized rates,
-latency series, slot busy time) — per DAG, per routing policy, and through
-the fleet co-simulation path — while running the whole time loop inside one
-XLA program.  The measurement satellites are pinned here too: the stability
-slope is per *second* (verdicts invariant to ``latency_sample_every``),
-``slot_busy`` covers exactly the post-warmup window of the realized horizon,
-and the short-run tail window is explicit.
+latency series, slot busy time) — for every DAG of ``ALL_DAGS``, per routing
+policy, and through the fleet co-simulation path — while running the whole
+time loop inside one XLA program.  The measurement satellites are pinned
+here too: the stability slope is per *second* (verdicts invariant to
+``latency_sample_every``), ``slot_busy`` covers exactly the post-warmup
+window of the realized horizon, and the short-run tail window is explicit.
 """
 
 import numpy as np
 import pytest
 
-from repro.core import (DataflowSimulator, RoutingPolicy, SweepBatch,
-                        diamond_dag, linear_dag, paper_library, plan,
+from repro.core import (ALL_DAGS, DataflowSimulator, RoutingPolicy,
+                        SweepBatch, linear_dag, paper_library, plan,
                         plan_fleet, simulate_fleet)
 from repro.core.predictor import effective_capacity_matrix
 
 RAW_FIELDS = ("queues", "busy", "served", "realized", "latency")
+EVERY_DAG = pytest.mark.parametrize("mk", list(ALL_DAGS.values()),
+                                    ids=list(ALL_DAGS))
+POLICIES = pytest.mark.parametrize("policy", list(RoutingPolicy),
+                                   ids=[p.value for p in RoutingPolicy])
 
 
 @pytest.fixture(scope="module")
@@ -44,13 +48,13 @@ def _assert_raw_close(a, b, tol=1e-10):
 
 # -- engine equivalence --------------------------------------------------------
 
-@pytest.mark.parametrize("policy", list(RoutingPolicy),
-                         ids=[p.value for p in RoutingPolicy])
-def test_scan_matches_numpy_raw(lib, policy):
+@EVERY_DAG
+@POLICIES
+def test_scan_matches_numpy_raw(lib, policy, mk):
     """Raw state (queues, served, realized, latency, busy) matches to 1e-10
     across a sweep spanning stable and overloaded rates, and the derived
     SimResults agree field by field."""
-    sim = _sim(lib, policy=policy)
+    sim = _sim(lib, mk, policy=policy)
     omegas = np.linspace(20.0, 180.0, 13)
     kw = dict(duration=8.0, dt=0.1)
     _assert_raw_close(sim.sweep_raw(omegas, engine="numpy", **kw),
@@ -81,13 +85,22 @@ def test_scan_run_is_the_k1_column(lib):
         assert b.slot_busy[slot] == pytest.approx(busy, abs=1e-10)
 
 
-@pytest.mark.parametrize("policy", list(RoutingPolicy),
-                         ids=[p.value for p in RoutingPolicy])
-def test_fleet_cosim_scan_matches_numpy(lib, policy):
-    """Acceptance: a 2-DAG fleet co-simulated through one batched scan call
+#: Fleets of ``ALL_DAGS`` with a slot budget their plan fits.
+FLEETS = {
+    "linear+diamond": (("linear", "diamond"), 12),
+    "traffic+finance+grid": (("traffic", "finance", "grid"), 24),
+    "all": (tuple(ALL_DAGS), 36),
+}
+
+
+@pytest.mark.parametrize("fleet", list(FLEETS))
+@POLICIES
+def test_fleet_cosim_scan_matches_numpy(lib, policy, fleet):
+    """Acceptance: a fleet co-simulated through one batched scan call
     matches the numpy engine to <= 1e-10, under both routing policies."""
-    fp = plan_fleet({"linear": linear_dag(), "diamond": diamond_dag()}, lib,
-                    budget_slots=12)
+    names, budget = FLEETS[fleet]
+    fp = plan_fleet({n: ALL_DAGS[n]() for n in names}, lib,
+                    budget_slots=budget)
     kw = dict(duration=8.0, dt=0.1, policy=policy)
     rep_n = simulate_fleet(fp, lib, engine="numpy", **kw)
     rep_s = simulate_fleet(fp, lib, engine="scan", **kw)
@@ -123,8 +136,9 @@ def test_cosim_busy_adds_on_shared_slots(lib):
     np.testing.assert_allclose(both.busy, 2 * solo.busy, rtol=1e-12)
 
 
-def test_max_stable_rate_engines_agree(lib):
-    sim = _sim(lib)
+@EVERY_DAG
+def test_max_stable_rate_engines_agree(lib, mk):
+    sim = _sim(lib, mk)
     r_np = sim.max_stable_rate(duration=8.0, dt=0.1, engine="numpy")
     r_sc = sim.max_stable_rate(duration=8.0, dt=0.1, engine="scan")
     assert r_sc == pytest.approx(r_np, rel=0.02)

@@ -1,14 +1,8 @@
 """End-to-end behaviour tests for the paper's system.
 
 The full loop: profile (Alg. 1) -> allocate (MBA) -> map (SAM) -> predict
-(§8.5) -> simulate -> ENACT on real JAX devices, plus the LM-framework
-integrations (serving planner, data-pipeline planner, serve engine).
+(§8.5) -> simulate -> ENACT on real JAX devices.
 """
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
 
 from repro.core import (DataflowSimulator, RoutingPolicy, diamond_dag,
                         paper_library, plan)
@@ -50,63 +44,3 @@ def test_executor_slot_aware_routing():
     ex = StreamExecutor(schedule, lib, policy=RoutingPolicy.SLOT_AWARE)
     rep = ex.run(60, duration=0.8, batch=16)
     assert rep.tuples > 0 and rep.stable
-
-
-def test_serving_planner_scales_with_rate():
-    """MBA+SAM chip allocation for disaggregated serving grows with load."""
-    from repro.configs import get_config
-    from repro.serve import plan_serving
-    cfg = get_config("qwen2.5-32b")
-    lo = plan_serving(cfg, request_rate=1.0, prompt_len=2048, gen_len=128)
-    hi = plan_serving(cfg, request_rate=8.0, prompt_len=2048, gen_len=128)
-    assert hi.prefill_chips >= lo.prefill_chips
-    assert hi.decode_chips >= lo.decode_chips
-    assert hi.schedule.acquired_slots >= lo.schedule.acquired_slots
-
-
-def test_serve_engine_end_to_end(key):
-    """Continuous batching: three requests share the decode batch and all
-    finish with the requested number of tokens."""
-    from repro.configs import get_config
-    from repro.models import default_env, get_model
-    from repro.serve import ServeEngine
-    cfg = get_config("minicpm-2b").reduced()
-    api = get_model(cfg)
-    env = default_env()
-    params = api.init(key)
-    eng = ServeEngine(api, env, params, max_batch=4, max_len=64)
-    rng = np.random.default_rng(0)
-    rids = [eng.submit(rng.integers(0, cfg.vocab_size, 8), max_new_tokens=6)
-            for _ in range(3)]
-    done = eng.run(max_ticks=50)
-    assert sorted(r.rid for r in done) == sorted(rids)
-    for r in done:
-        assert len(r.output) == 6
-        assert r.first_token_at is not None and r.finished_at is not None
-
-
-def test_data_pipeline_plan_and_run():
-    from repro.data import TokenPipeline, plan_pipeline
-    schedule = plan_pipeline(20000)
-    assert schedule.allocation.tasks["parse"].threads >= 1
-    pipe = TokenPipeline(seq_len=64, batch_size=4, schedule=schedule)
-    batches = list(pipe.batches(3))
-    assert len(batches) == 3
-    for b in batches:
-        assert b["tokens"].shape == (4, 64)
-        np.testing.assert_array_equal(b["labels"][:, :-1], b["tokens"][:, 1:])
-
-
-def test_hlo_collective_parser():
-    from repro.distributed.hloparse import parse_collectives
-    hlo = """
-  %ag = bf16[16,1024]{1,0} all-gather(%x), replica_groups={{0,1,2,3}}, dimensions={0}
-  %ar = f32[256]{0} all-reduce(%y), replica_groups=[8,2]<=[16], to_apply=%add
-  %a2a.1 = (f32[4,8]{1,0}, f32[4,8]{1,0}) all-to-all(%a, %b), replica_groups={{0,1}}
-"""
-    stats = parse_collectives(hlo)
-    assert stats.counts == {"all-gather": 1, "all-reduce": 1, "all-to-all": 1}
-    assert stats.raw_bytes["all-gather"] == 16 * 1024 * 2
-    # ring factors: AG (g-1)/g with g=4; AR 2*(g-1)/g with g=2
-    assert stats.wire_bytes["all-gather"] == pytest.approx(16 * 1024 * 2 * 3 / 4)
-    assert stats.wire_bytes["all-reduce"] == pytest.approx(256 * 4 * 2 * 1 / 2)
